@@ -1,11 +1,14 @@
 """Cold-start smoke: serve twice, assert warm start actually warms.
 
 Runs ``repro.launch.serve --scenario bursty --quick`` in two fresh
-subprocesses sharing one ``--cache-dir``.  The first process compiles
-everything and resolves every lane cold, then snapshots; the second must
+subprocesses sharing one lane-snapshot ``--cache-dir`` (and the launcher's
+XLA compile cache, in ``JAX_COMPILATION_CACHE_DIR`` or the checkout's
+``.jax_cache``).  The first process resolves every lane cold, then
+snapshots; the parent never imports JAX, so each child may hold the
+device.  The second must
 
-* report a strictly better ``serve/time_to_first_batch`` (persistent XLA
-  compile cache + lane snapshot replace the dominant cold costs), and
+* report a strictly better ``serve/time_to_first_batch`` (compile cache
+  + lane snapshot replace the dominant cold costs), and
 * do ZERO lane re-resolves for cached keys — ``serve/lane_cache``
   misses == 0, every telemetry lane replayed from the snapshot.
 

@@ -27,13 +27,11 @@ on/off, PIM + baseline points):
   (bounded by visible devices; bit-exactness asserted).
 * ``fleet/pallas_*`` — the Pallas lane-resolver backend vs the scan
   resolver on the same prebuilt streams (bounded subset; interpret mode
-  on CPU, so a parity/portability row — native on TPU).
+  off TPU, so a parity/portability row; on TPU Mosaic refuses the kernel
+  and the row reports ``unsupported``).
 * ``fleet/serve_replan_*`` — repeated serving-loop telemetry queries
   (fresh planner per query, the replan pattern) with the resolved-lane
   LRU disabled vs enabled.
-* ``fleet/coldstart_*`` — a fresh subprocess workload run cold vs warm
-  against one persistent ``--cache-dir`` (XLA compile cache + lane
-  snapshot); the warm child must replay with zero lane resolves.
 * ``fleet/policy_*`` — adaptive offload control closed-loop over a
   bursty serving trace: per-step recompute vs hysteresis vs sticky on
   control cost (us/step, planner queries) with the realized/oracle
@@ -72,8 +70,6 @@ except ImportError:          # run as a script: benchmarks/ is sys.path[0]
     from _xla_host_devices import force_host_devices
 force_host_devices()
 
-import json
-import subprocess
 import tempfile
 import time
 
@@ -81,61 +77,20 @@ import numpy as np
 
 from repro.core import engine, faults, warmstart
 from repro.core.pimsim import PimSimulator
-
-# Honour REPRO_CACHE_DIR: benchmark runs share the launchers' persistent
-# warm-start plumbing (no-op when the env knob is unset).
-warmstart.enable_warm_start()
 from repro.core.timing import DEFAULT_SYSTEM, LpddrTimings, SystemSpec
 from repro.pimkernel.executor import GemvRequest, PimExecutor, spec_context
-from repro.pimkernel.tileconfig import ALL_DTYPES, PimDType
-
-DIMS = [512, 1024, 2048, 4096, 8192]
-QUICK_DIMS = [512, 1024, 2048]
-BASE = 4096
-
-# Cold-start probe child: a fresh process resolving a small PIM grid
-# under --cache-dir semantics (warm-start load at entry, snapshot save at
-# exit), reporting elapsed wall, lane-cache misses and the cycle totals
-# as one JSON line.  Run twice against the same directory, the second
-# process must reproduce the totals byte-identically with ZERO fleet
-# resolves — the process-level analogue of the serve_replan rows.
-_COLDSTART_CHILD = r"""
-import json, sys, time
-t0 = time.perf_counter()
-from repro.core import engine, warmstart
-from repro.core.timing import DEFAULT_SYSTEM
-from repro.pimkernel.executor import GemvRequest, PimExecutor
+from repro.pimkernel.grids import FIG4_BASE as BASE
+from repro.pimkernel.grids import FIG4_DIMS as DIMS
+from repro.pimkernel.grids import fig4_grid
 from repro.pimkernel.tileconfig import PimDType
-warmstart.enable_warm_start(sys.argv[1])
-reqs = [GemvRequest.pim(1024, d, PimDType.W8A8) for d in (256, 512)] + \
-    [GemvRequest.baseline(1024, 256, PimDType.W8A8)]
-res = PimExecutor(DEFAULT_SYSTEM).run_many(reqs)
-info = engine.lane_cache_info()
-warmstart.save_warm_start(sys.argv[1])
-print(json.dumps(dict(elapsed=time.perf_counter() - t0,
-                      totals=[int(r.cycles) for r in res],
-                      misses=info["misses"])))
-"""
 
-
-def fig4_grid(dims=None) -> list[GemvRequest]:
-    """Every (axis, dtype, dim, fence) point of Fig. 4 + its baseline."""
-    reqs: list[GemvRequest] = []
-    seen: set = set()
-    for fence in (False, True):
-        for axis in ("activation", "output"):
-            for dt in ALL_DTYPES:
-                for d in dims or DIMS:
-                    H, W = (BASE, d) if axis == "activation" else (d, BASE)
-                    for r in (GemvRequest.pim(H, W, dt, fence=fence),
-                              GemvRequest.baseline(H, W, dt)):
-                        if r.key not in seen:
-                            seen.add(r.key)
-                            reqs.append(r)
-    return reqs
-
+QUICK_DIMS = [512, 1024, 2048]
 
 def main(quick: bool = False) -> dict:
+    # The launchers' persistent caches: compile cache always, lane
+    # snapshot when REPRO_CACHE_DIR is set.
+    warmstart.enable_compilation_cache()
+    warmstart.enable_warm_start()
     dims = QUICK_DIMS if quick else DIMS
     ex = PimExecutor(DEFAULT_SYSTEM)
     reqs = fig4_grid(dims)
@@ -228,10 +183,9 @@ def main(quick: bool = False) -> dict:
           f"{resolve_batch_s/mesh_best_s:.1f}")
 
     # Pallas lane resolver vs the scan resolver on the same prebuilt
-    # streams (a bounded subset — on this CPU container the kernel runs
-    # under the Pallas *interpreter*, so the row is an honest parity/
-    # portability report, not a speed claim; the crossover is native TPU
-    # compilation, where the same kernel keeps lane state in VMEM).
+    # streams (a bounded subset — off TPU the kernel runs under the
+    # Pallas *interpreter*, so the row is an honest parity/portability
+    # report, not a speed claim; on TPU Mosaic refuses the kernel today).
     # Bit-exactness asserted like every other row.
     from repro.kernels import lane_scan
     pallas_speedup = None
@@ -620,31 +574,6 @@ def main(quick: bool = False) -> dict:
     else:
         print("fleet/chaos_degraded,terminal_rung_only,1.00")
 
-    # Cold vs warm process start: same child workload twice against one
-    # persistent cache dir.  The warm child must produce byte-identical
-    # totals with zero lane-cache misses (every lane replayed from the
-    # snapshot, XLA executables from the compile cache).
-    with tempfile.TemporaryDirectory(prefix="repro-warm-") as cache_dir:
-        runs = []
-        for _ in range(2):
-            out = subprocess.run(
-                [sys.executable, "-c", _COLDSTART_CHILD, cache_dir],
-                capture_output=True, text=True, check=True)
-            runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
-    cold_run, warm_run = runs
-    assert warm_run["totals"] == cold_run["totals"], \
-        "warm-start replay must be bit-identical"
-    assert warm_run["misses"] == 0, \
-        (f"warm process resolved lanes it should have replayed: "
-         f"{warm_run['misses']} misses")
-    coldstart_speedup = cold_run["elapsed"] / warm_run["elapsed"]
-    print(f"fleet/coldstart_cold,{cold_run['elapsed']*1e3:.0f},"
-          f"{cold_run['misses']}")
-    print(f"fleet/coldstart_warm,{warm_run['elapsed']*1e3:.0f},"
-          f"{warm_run['misses']}")
-    print(f"fleet/coldstart_speedup,{warm_run['elapsed']*1e3:.0f},"
-          f"{coldstart_speedup:.2f}")
-
     return dict(points=n,
                 devices=len(engine.lane_devices()),
                 plan_speedup=plan_ref_s / plan_vec_s,
@@ -660,9 +589,6 @@ def main(quick: bool = False) -> dict:
                 specfam_spec_decode=specfam_spec_decode,
                 serve_replan_speedup=replan_cold_s / replan_warm_s,
                 pallas_speedup=pallas_speedup,
-                coldstart_speedup=coldstart_speedup,
-                coldstart_cold_s=cold_run["elapsed"],
-                coldstart_warm_s=warm_run["elapsed"],
                 policy_efficiency={p: r["efficiency"]
                                    for p, r in policy_reports.items()},
                 policy_queries={p: r["planner_queries"]

@@ -1,9 +1,10 @@
 """JAX cycle-accurate timing engine — the fleet execution core.
 
 The same semantics as ``engine_ref.RefEngine`` expressed as a
-``jax.lax.scan`` over the command stream with a branchless, opcode-masked
-step (see ``_build_step``).  The scan carry is a :class:`ChannelState`
-pytree; each step emits the command's issue cycle.
+``jax.lax.scan`` over the command stream (block by block, see
+``_lane_runner``) with a branchless, opcode-masked step (see
+``_build_step``).  The scan carry is a :class:`ChannelState` pytree; each
+step emits the command's issue cycle.
 
 Unlike the original per-spec design (one compilation per ``TimingCycles``
 instance), the timing configuration is a *traced* pytree argument of the
@@ -32,10 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:                                   # jax >= 0.6 promotes it to jax.*
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import commands as C
@@ -319,20 +316,42 @@ def scan_unroll() -> int:
     return env if env >= 1 else _SCAN_UNROLL
 
 
+# Commands per inner-loop block (see _lane_runner).
+_BLOCK = 128
+
+
 def _lane_runner(num_banks: int, unroll: int | None = None):
-    """The single-lane scan ``(cyc, stream) -> (issue, total)`` for one
+    """The single-lane loop ``(cyc, stream) -> (issue, total)`` for one
     bank count — the body the vmapped, shard_map and Pallas resolvers
-    all wrap, so every backend shares semantics by construction."""
+    all wrap, so every backend shares semantics by construction.
+
+    ``stream`` is int32 ``(4, N)``: one row per command field, commands
+    along the minor axis.  An outer loop takes aligned blocks of up to
+    ``_BLOCK`` commands and an inner scan steps through each block.  A
+    scan over the whole command-major ``(N, 4)`` stream instead makes
+    the TPU compiler copy it with 4 on its 128-wide minor axis, 32x
+    padded (16 GB for four 6.3 M-command lanes, more than a v5e holds);
+    per block, that copy is a few KB.
+    """
     step = _build_step(num_banks)
     if unroll is None:
         unroll = scan_unroll()
 
     def run_one(cyc, stream):
-        def body(st, cmd):
-            return step(cyc, st, cmd)
+        n = stream.shape[1]
+        blk = min(_BLOCK, n & -n)          # length buckets are k * 2^j
 
-        st, issue = jax.lax.scan(body, _fresh_state(num_banks), stream,
-                                 unroll=unroll)
+        def run_block(j, carry):
+            st, issue = carry
+            block = jax.lax.dynamic_slice_in_dim(stream, j * blk, blk, 1)
+            st, out = jax.lax.scan(lambda st, cmd: step(cyc, st, cmd), st,
+                                   block.T, unroll=unroll)
+            return st, jax.lax.dynamic_update_slice_in_dim(
+                issue, out, j * blk, 0)
+
+        st, issue = jax.lax.fori_loop(
+            0, n // blk, run_block,
+            (_fresh_state(num_banks), jnp.zeros((n,), I32)))
         return issue, st.drain
 
     return run_one
@@ -343,9 +362,10 @@ def _fleet_resolver(num_banks: int):
 
     ``fn(cycs, streams)`` where ``cycs`` is a :class:`TimingCycles` pytree
     stacked along the fleet axis (every data leaf shape ``(F,)``) and
-    ``streams`` is int32 ``(F, N, 4)``; returns ``(issue (F, N), total
-    (F,))``.  The timing configuration is traced, so the jit cache keys
-    only on shapes — new spec variants reuse the existing executable.
+    ``streams`` is int32 ``(F, 4, N)`` (field-major, see
+    :func:`_lane_runner`); returns ``(issue (F, N), total (F,))``.  The
+    timing configuration is traced, so the jit cache keys only on shapes
+    — new spec variants reuse the existing executable.
     """
     key = (num_banks, scan_unroll())
     fn = _RESOLVERS.get(key)
@@ -371,9 +391,12 @@ def _mesh_resolver(num_banks: int, mesh: Mesh):
     fn = _MESH_RESOLVERS.get(key)
     if fn is None:
         spec = PartitionSpec(mesh.axis_names[0])
-        fn = jax.jit(_shard_map(jax.vmap(_lane_runner(num_banks, key[2])),
-                                mesh=mesh, in_specs=(spec, spec),
-                                out_specs=(spec, spec)))
+        # check_vma=False: the scan's fresh carry is mesh-invariant and
+        # its output varies over ``lanes``; lanes never communicate, so
+        # there is no replication for the checker to track.
+        fn = jax.jit(jax.shard_map(jax.vmap(_lane_runner(num_banks, key[2])),
+                                   mesh=mesh, in_specs=(spec, spec),
+                                   out_specs=(spec, spec), check_vma=False))
         _MESH_RESOLVERS[key] = fn
     return fn
 
@@ -414,10 +437,11 @@ def compile_cache_size() -> int:
 # Lane-resolver backend selection: "scan" is the vmapped lax.scan family
 # (single-device / threaded / shard_map dispatch above it); "pallas" swaps
 # the per-slab executable for the Pallas lane kernel, keeping the dedupe /
-# LRU / slab machinery identical.  "auto" resolves to pallas when the
-# kernel is supported on this backend, scan otherwise — and an explicit
-# "pallas" request ALSO falls back to scan when unsupported (capability-
-# detected fallback; the parity suites pin bit-identity between the two).
+# LRU / slab machinery identical.  "auto" is decided from the platform in
+# ONE place, resolved_lane_backend: pallas where the kernel runs
+# (lane_scan.pallas_lane_supported), scan elsewhere.  An explicit
+# "pallas" request never becomes another backend: where the kernel cannot
+# be built it raises the compiler's reason.
 #
 # Execution follows the DEGRADATION LADDER pallas → mesh → threaded →
 # single-device scan (see _ladder_rungs): a run starts on the highest
@@ -577,14 +601,19 @@ def lane_backend(scope: BackendScope | None = None) -> str:
 def resolved_lane_backend(scope: BackendScope | None = None) -> str:
     """The backend slabs will actually run on: "scan" or "pallas".
 
-    "pallas"/"auto" requests degrade to "scan" when the Pallas kernel is
-    not runnable here (capability probe, cached per process).
+    The one place "auto" is decided: pallas on a platform where the lane
+    kernel runs, scan elsewhere (today: scan on TPU, where Mosaic refuses
+    the kernel).  An explicit "pallas" request builds the kernel first
+    and raises the compiler's reason where it cannot be built.
     """
     req = lane_backend(scope)
     if req == "scan":
         return "scan"
     from repro.kernels import lane_scan
-    return "pallas" if lane_scan.pallas_lane_supported() else "scan"
+    if req == "auto":
+        return "pallas" if lane_scan.pallas_lane_supported() else "scan"
+    lane_scan.require_lane_kernel()
+    return "pallas"
 
 
 class lane_backend_scope:
@@ -965,6 +994,26 @@ class lane_mesh_scope:
         return False
 
 
+# Padded slab rows resolved per device, counted from the result arrays'
+# own shards — which device really ran each slab or mesh shard, not
+# which one it was meant for.
+_SLAB_ROWS: dict[str, int] = {}
+_SLAB_ROWS_LOCK = threading.Lock()
+
+
+def _count_slab_rows(out: jax.Array) -> None:
+    with _SLAB_ROWS_LOCK:
+        for shard in out.addressable_shards:
+            key = str(shard.device)
+            _SLAB_ROWS[key] = _SLAB_ROWS.get(key, 0) + shard.data.shape[0]
+
+
+def slab_device_rows() -> dict[str, int]:
+    """Padded lane rows resolved so far, per device (process lifetime)."""
+    with _SLAB_ROWS_LOCK:
+        return dict(_SLAB_ROWS)
+
+
 def _mesh_width(n: int, m: int) -> int:
     """Global fleet width for ``n`` lanes on an ``m``-way mesh.
 
@@ -984,17 +1033,18 @@ _SLAB_POOL_LOCK = threading.Lock()
 
 
 def _take_slab(width: int, length: int) -> np.ndarray:
+    """A zeroed ``(width, 4, length)`` slab: field-major lanes, NOP tails."""
     with _SLAB_POOL_LOCK:
         spares = _SLAB_POOL.get((width, length))
         buf = spares.pop() if spares else None
     if buf is None:
-        return np.zeros((width, length, 4), dtype=np.int32)
+        return np.zeros((width, 4, length), dtype=np.int32)
     buf.fill(0)
     return buf
 
 
 def _give_slab(buf: np.ndarray) -> None:
-    key = (buf.shape[0], buf.shape[1])
+    key = (buf.shape[0], buf.shape[2])
     with _SLAB_POOL_LOCK:
         spares = _SLAB_POOL.setdefault(key, [])
         if len(spares) < 2:
@@ -1182,12 +1232,13 @@ def resolve_lanes(
                 buf = _take_slab(width, length)
                 for row, u in enumerate(chunk):
                     s = uniq[u][1]
-                    buf[row, : s.shape[0]] = s
+                    buf[row, :, : s.shape[0]] = s.T
                 cycs = [uniq[u][0] for u in chunk]
                 cycs += [cycs[0]] * (width - len(chunk))
                 placed = (jax.device_put(stack_cycles(cycs), sharding),
                           jax.device_put(buf, sharding))
                 iss, tot = _mesh_resolver(nb, mesh)(*placed)
+                _count_slab_rows(tot)
                 tot = np.asarray(tot)
                 _store(chunk, np.asarray(iss) if need_issue else None, tot)
                 _give_slab(buf)
@@ -1223,7 +1274,7 @@ def resolve_lanes(
             buf = _take_slab(width, length)
             for row, u in enumerate(chunk):
                 s = uniq[u][1]
-                buf[row, : s.shape[0]] = s
+                buf[row, :, : s.shape[0]] = s.T
             cycs = [uniq[u][0] for u in chunk]
             cycs += [cycs[0]] * (width - len(chunk))
             dev = devs[assignment[i]]
@@ -1235,6 +1286,7 @@ def resolve_lanes(
         def _run_dev(jobs) -> None:
             for nb, chunk, (cycs, batch) in jobs:
                 iss, tot = resolver(nb)(cycs, batch)
+                _count_slab_rows(tot)
                 tot = np.asarray(tot)
                 _store(chunk, np.asarray(iss) if need_issue else None,
                        tot)

@@ -18,6 +18,8 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
+
 
 @dataclasses.dataclass(frozen=True)
 class LpddrTimings:
@@ -162,17 +164,12 @@ class TimingCycles:
         return dataclasses.astuple(self)
 
 
-try:  # register lazily so numpy-only users never pay the jax import
-    import jax.tree_util as _jtu
-
-    _jtu.register_dataclass(
-        TimingCycles,
-        data_fields=[f.name for f in dataclasses.fields(TimingCycles)
-                     if f.name != "num_banks"],
-        meta_fields=["num_banks"],
-    )
-except ImportError:  # pragma: no cover - jax is a hard dep elsewhere
-    pass
+jax.tree_util.register_dataclass(
+    TimingCycles,
+    data_fields=[f.name for f in dataclasses.fields(TimingCycles)
+                 if f.name != "num_banks"],
+    meta_fields=["num_banks"],
+)
 
 
 # A default spec used across tests/benchmarks.

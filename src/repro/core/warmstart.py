@@ -6,9 +6,11 @@ steady-state throughput: XLA recompilation of the fleet resolvers
 resolved-lane LRU (every structural stream key re-resolved once).  Both
 are pure caches of deterministic computations, so both persist:
 
-* :func:`enable_compilation_cache` points JAX's persistent compilation
-  cache at ``<cache_dir>/xla`` — the second process deserializes the
-  compiled executables instead of rebuilding them.
+* :func:`enable_compilation_cache` turns on JAX's persistent compilation
+  cache.  Its directory comes from outside: ``JAX_COMPILATION_CACHE_DIR``
+  when set (JAX reads it itself; no other directory is set in code),
+  else one fixed path inside the checkout, ``<repo>/.jax_cache``.  The
+  path is part of the cache key, so it never moves.
 * :func:`save_lane_snapshot` / :func:`load_lane_snapshot` round-trip the
   engine's resolved-lane LRU (``engine.lane_cache_export`` /
   ``lane_cache_import``) through a versioned, fingerprinted pickle at
@@ -23,8 +25,9 @@ pickle, wrong version, wrong fingerprint — degrades to a cold cache
 instead of raising.  Writes are atomic (tmp + ``os.replace``) so a
 crashed writer can at worst leave the previous snapshot in place.
 
-The launchers (``launch/serve.py`` / ``dryrun.py`` / ``train.py``) and
-benchmarks wire this behind ``--cache-dir`` / ``REPRO_CACHE_DIR`` via
+The launchers (``launch/serve.py`` / ``dryrun.py`` / ``train.py``) call
+:func:`enable_compilation_cache` at startup; ``serve`` and ``dryrun``
+wire the lane snapshot behind ``--cache-dir`` / ``REPRO_CACHE_DIR`` via
 :func:`enable_warm_start` at startup and :func:`save_warm_start` at exit.
 """
 from __future__ import annotations
@@ -156,36 +159,32 @@ def _reject_reason(payload) -> str | None:
 # XLA persistent compilation cache
 # ---------------------------------------------------------------------------
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``<cache_dir>/xla``.
+_ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
+# <repo>/.jax_cache: this file is <repo>/src/repro/core/warmstart.py
+_CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    Thresholds are dropped to zero so even the engine's small resolver
-    jits persist (the defaults skip sub-second compiles, which is exactly
-    the population a simulator cold-start is made of).  Version-tolerant:
-    tries the modern ``jax.config`` flags first, falls back to the
-    ``compilation_cache.set_cache_dir`` API, and reports False (warm
-    start degrades to lane snapshot only) if neither exists.
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses it and no
+    directory is set here.  Otherwise the cache goes to the fixed
+    in-checkout path.  Thresholds drop to zero so even the engine's
+    small resolver jits persist (the defaults skip sub-second compiles,
+    which is exactly the population a simulator cold start is made of).
     """
-    xla_dir = os.path.join(cache_dir, "xla")
-    os.makedirs(xla_dir, exist_ok=True)
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:  # noqa: BLE001 - thresholds are best-effort
-            pass
-        return True
-    except Exception:      # noqa: BLE001 - older jax: legacy API below
-        pass
-    try:
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.set_cache_dir(xla_dir)
-        return True
-    except Exception:      # noqa: BLE001 - no persistent cache available
-        return False
+    path = os.environ.get(_ENV_COMPILE_CACHE, "").strip()
+    if not path:
+        path = _CHECKOUT_COMPILE_CACHE
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
 
 
 # ---------------------------------------------------------------------------
@@ -193,20 +192,18 @@ def enable_compilation_cache(cache_dir: str) -> bool:
 # ---------------------------------------------------------------------------
 
 def enable_warm_start(cache_dir: str | None = None) -> dict:
-    """Enable every persistent cache under ``cache_dir`` (or env knob).
+    """Load the lane snapshot under ``cache_dir`` (or env knob).
 
-    Returns a small report ``{"cache_dir", "compile_cache", "lanes"}``;
-    with no directory configured it is a no-op reporting
-    ``{"cache_dir": None, ...}`` so launchers can call it
-    unconditionally.
+    Returns a small report ``{"cache_dir", "lanes"}``; with no directory
+    configured it is a no-op reporting ``{"cache_dir": None, "lanes":
+    0}`` so launchers can call it unconditionally.  The compile cache is
+    not tied to ``cache_dir``: see :func:`enable_compilation_cache`.
     """
     cache_dir = cache_dir or cache_dir_from_env()
     if not cache_dir:
-        return {"cache_dir": None, "compile_cache": False, "lanes": 0}
+        return {"cache_dir": None, "lanes": 0}
     os.makedirs(cache_dir, exist_ok=True)
-    ok = enable_compilation_cache(cache_dir)
-    lanes = load_lane_snapshot(cache_dir)
-    return {"cache_dir": cache_dir, "compile_cache": ok, "lanes": lanes}
+    return {"cache_dir": cache_dir, "lanes": load_lane_snapshot(cache_dir)}
 
 
 def save_warm_start(cache_dir: str | None = None) -> int:

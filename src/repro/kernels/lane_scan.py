@@ -15,13 +15,17 @@ mesh resolver shares it.  The differential suites
 (``tests/test_pallas_resolver.py``, the conformance battery run under
 ``REPRO_LANE_BACKEND=pallas``) enforce the contract.
 
-Interpret-mode plumbing mirrors ``kernels/ops.py``: on CPU the kernel
-runs under the Pallas interpreter (how CI exercises it); on TPU the same
-kernel compiles natively.  :func:`pallas_lane_supported` is the
-capability probe behind the engine's automatic backend fallback — any
-failure to build/run the kernel, or a mismatch against the scan
-resolver on a tiny probe lane, degrades ``configure_lane_backend
-("pallas")`` to the scan path instead of breaking resolution.
+Interpret-mode plumbing mirrors ``kernels/ops.py``: off TPU the kernel
+runs under the Pallas interpreter (how CI exercises it).  On TPU, Mosaic
+refuses it today: the ``(1, 28)`` timing block and the ``(1, n)`` output
+blocks break the 8x128 tiling rule, and with legal blocks the body — a
+loop of ``dynamic_slice`` blocks and a ``lax.scan`` over the command
+stream — has no Mosaic lowering (``NotImplementedError``: unimplemented
+primitive ``dynamic_slice``; the scan fails in ``_scan_lowering_rule``).
+:func:`pallas_lane_supported` states that as a platform rule, which is
+what ``auto`` follows; an explicit ``pallas`` request builds the kernel
+through :func:`require_lane_kernel` and raises the compiler's reason
+where it cannot be built.
 """
 from __future__ import annotations
 
@@ -32,12 +36,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import commands as C
 from repro.core import engine as _engine
 from repro.core.timing import DEFAULT_SYSTEM, TimingCycles
 from .ops import default_interpret
-from .pim_gemv import _CompilerParams
 
 # The timing configuration rides the grid as an int32 matrix: one row per
 # lane, one column per cycle field (``tck_ns``/``num_banks`` excluded —
@@ -48,8 +52,9 @@ CYC_FIELDS = tuple(f.name for f in dataclasses.fields(TimingCycles)
 
 def _lane_kernel(cyc_ref, stream_ref, issue_ref, total_ref, *,
                  num_banks: int, unroll: int):
-    """One grid step = one lane: scan the command stream with the shared
-    step body; carry (the ChannelState pytree) lives in VMEM/registers."""
+    """One grid step = one lane: loop over the command stream with the
+    shared step body; carry (the ChannelState pytree) lives in
+    VMEM/registers."""
     cyc = TimingCycles(
         tck_ns=0.0, num_banks=num_banks,
         **{name: cyc_ref[0, j] for j, name in enumerate(CYC_FIELDS)})
@@ -72,7 +77,7 @@ def make_lane_resolver(num_banks: int, unroll: int | None = None,
 
     The returned ``fn(cycs, streams)`` honours the exact
     ``engine._fleet_resolver`` contract — ``cycs`` a ``TimingCycles``
-    pytree stacked along the fleet axis, ``streams`` int32 ``(F, N, 4)``,
+    pytree stacked along the fleet axis, ``streams`` int32 ``(F, 4, N)``,
     result ``(issue (F, N), total (F,))`` int32 — so the engine's slab
     dispatch, dedupe and lane LRU are backend-oblivious.  The jit cache
     keys only on shapes (the timing data is traced), preserving the
@@ -85,14 +90,14 @@ def make_lane_resolver(num_banks: int, unroll: int | None = None,
     ncyc = len(CYC_FIELDS)
 
     def fn(cycs, streams):
-        f, n, _ = streams.shape
+        f, _, n = streams.shape
         interp = default_interpret() if interpret is None else interpret
         issue, total = pl.pallas_call(
             kern,
             grid=(f,),
             in_specs=[
                 pl.BlockSpec((1, ncyc), lambda i: (i, 0)),
-                pl.BlockSpec((1, n, 4), lambda i: (i, 0, 0)),
+                pl.BlockSpec((1, 4, n), lambda i: (i, 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, n), lambda i: (i, 0)),
@@ -102,7 +107,7 @@ def make_lane_resolver(num_banks: int, unroll: int | None = None,
                 jax.ShapeDtypeStruct((f, n), jnp.int32),
                 jax.ShapeDtypeStruct((f, 1), jnp.int32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interp,
         )(pack_cycles(cycs), streams)
@@ -122,24 +127,34 @@ def _probe_stream(num_banks: int) -> np.ndarray:
     return s
 
 
-@functools.lru_cache(maxsize=None)
 def pallas_lane_supported() -> bool:
-    """Capability probe behind the engine's automatic backend fallback.
+    """Whether the lane kernel runs here — the platform rule ``auto``
+    follows.
 
-    Builds and runs the kernel on one probe lane and demands bit-identity
-    with the scan resolver; any exception (Pallas feature missing on this
-    jax version/backend) or mismatch reports unsupported.  Cached per
-    process — the probe costs two tiny compiles, once.
+    It runs only where Pallas interprets it: on TPU, where kernels
+    compile natively, Mosaic refuses it (see the module docstring), so
+    ``auto`` means the scan resolver there until the kernel is
+    rewritten.
     """
-    try:
-        cyc = DEFAULT_SYSTEM.derive_cycles()
-        stream = _probe_stream(cyc.num_banks)[None]
-        cycs = _engine.stack_cycles([cyc])
-        ref_iss, ref_tot = _engine._fleet_resolver(cyc.num_banks)(
-            cycs, stream)
-        got_iss, got_tot = make_lane_resolver(cyc.num_banks)(cycs, stream)
-        return (np.array_equal(np.asarray(got_iss), np.asarray(ref_iss))
-                and np.array_equal(np.asarray(got_tot),
-                                   np.asarray(ref_tot)))
-    except Exception:          # noqa: BLE001 - any failure means fallback
-        return False
+    return default_interpret()
+
+
+@functools.lru_cache(maxsize=None)
+def require_lane_kernel() -> None:
+    """Build and run the kernel on one probe lane, demanding bit-identity
+    with the scan resolver.
+
+    Raises whatever the compiler raises where the kernel cannot be built
+    (an explicit ``pallas`` request then fails with that reason instead
+    of running another backend), and ``RuntimeError`` on a mismatch.
+    Cached per process once it has passed.
+    """
+    cyc = DEFAULT_SYSTEM.derive_cycles()
+    stream = _probe_stream(cyc.num_banks).T[None]
+    cycs = _engine.stack_cycles([cyc])
+    ref_iss, ref_tot = _engine._fleet_resolver(cyc.num_banks)(cycs, stream)
+    got_iss, got_tot = make_lane_resolver(cyc.num_banks)(cycs, stream)
+    if not (np.array_equal(np.asarray(got_iss), np.asarray(ref_iss))
+            and np.array_equal(np.asarray(got_tot), np.asarray(ref_tot))):
+        raise RuntimeError("pallas lane kernel disagrees with the scan "
+                           "resolver on the probe lane")

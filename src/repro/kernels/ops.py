@@ -3,7 +3,7 @@
 `pim_linear` is the layer-facing entry point: it takes a float input and a
 pre-quantized weight bundle (see :func:`prepare_weights`), quantizes the
 activations on the fly, and dispatches to the Pallas kernel (interpret
-mode on CPU — the TPU path compiles the same kernel natively).
+mode off TPU, native on TPU: see :func:`default_interpret`).
 
 The default block shapes come from the PIM tile configuration: the Data
 Mapper's ``T_h x T_w`` scaled to MXU alignment (DESIGN.md §2.3), so the
@@ -23,6 +23,8 @@ from . import pim_gemm, pim_gemv, ref
 
 
 def default_interpret() -> bool:
+    """What ``interpret=None`` means for every kernel of this package:
+    compile natively on TPU, run the Pallas interpreter elsewhere."""
     return jax.default_backend() != "tpu"
 
 
@@ -60,8 +62,6 @@ def prepare_weights(w, dtype: PimDType | str) -> QuantWeights:
 def pim_linear(x, qw: QuantWeights, *, block=None,
                interpret: bool | None = None) -> jnp.ndarray:
     """y = x @ W^T with PIM-tile kernels.  x: (W,) or (B, W) float."""
-    if interpret is None:
-        interpret = default_interpret()
     if block is None:
         block = pim_block_shape(qw.dtype)
     x = jnp.asarray(x)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pim_gemv import _CompilerParams, _pad_to
+from .pim_gemv import _interpret, _pad_to
 
 
 def _gemm_int_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, w_bits: int,
@@ -64,7 +64,7 @@ def _gemm_fp_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_w: int):
                    static_argnames=("w_bits", "block", "interpret"))
 def pim_gemm_int(wq, xb_q, w_scale, x_scale, *, w_bits: int = 8,
                  block: tuple[int, int, int] = (8, 256, 512),
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Quantized GEMM: (B, W) x (H, W[/2]) -> f32 (B, H)."""
     bb, bh, bw = block
     b, _ = xb_q.shape
@@ -89,16 +89,16 @@ def pim_gemm_int(wq, xb_q, w_scale, x_scale, *, w_bits: int = 8,
         out_specs=pl.BlockSpec((bb, bh), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, hp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bh), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(xb_q, wq, ws)
     return out[:b, :h]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def pim_gemm_fp(w_fp8, xb, *, block: tuple[int, int, int] = (8, 256, 512),
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """fp8 weight GEMM: (B, W) x (H, W) -> f32 (B, H)."""
     bb, bh, bw = block
     b = xb.shape[0]
@@ -119,8 +119,8 @@ def pim_gemm_fp(w_fp8, xb, *, block: tuple[int, int, int] = (8, 256, 512),
         out_specs=pl.BlockSpec((bb, bh), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, hp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(xb, w_fp8)
     return out[:b, :h]

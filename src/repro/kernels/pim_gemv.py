@@ -25,14 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:  # fail at import, not inside pallas_call
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is unsupported")
-
 
 def _gemv_int_kernel(w_ref, x_ref, s_ref, o_ref, acc_ref, *, w_bits: int,
                      n_w: int):
@@ -78,6 +70,15 @@ def _gemv_fp_kernel(w_ref, x_ref, o_ref, acc_ref, *, n_w: int):
         o_ref[...] = acc_ref[...]
 
 
+def _interpret(interpret: bool | None) -> bool:
+    """``None`` -> interpret off TPU, compile on it (``ops.default_interpret``;
+    imported here because ``ops`` imports this module)."""
+    if interpret is None:
+        from .ops import default_interpret
+        return default_interpret()
+    return interpret
+
+
 def _pad_to(arr, axis, mult):
     n = arr.shape[axis]
     pad = (-n) % mult
@@ -91,7 +92,7 @@ def _pad_to(arr, axis, mult):
 @functools.partial(jax.jit, static_argnames=("w_bits", "block", "interpret"))
 def pim_gemv_int(wq, x_q, w_scale, x_scale, *, w_bits: int = 8,
                  block: tuple[int, int] = (256, 512),
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Quantized GEMV: (H, W[/2]) x (W,) -> f32 (H,).
 
     ``block`` is (BH, BW) in *element* space; the PIM-tile-derived default
@@ -119,16 +120,16 @@ def pim_gemv_int(wq, x_q, w_scale, x_scale, *, w_bits: int = 8,
         out_specs=pl.BlockSpec((bh, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((hp, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bh, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(wq, x_q, ws)
     return out[:h, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def pim_gemv_fp(w_fp8, x, *, block: tuple[int, int] = (256, 512),
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """fp8-e4m3 weight GEMV: (H, W) x (W,) -> f32 (H,)."""
     bh, bw = block
     h, w_elems = w_fp8.shape
@@ -147,8 +148,8 @@ def pim_gemv_fp(w_fp8, x, *, block: tuple[int, int] = (256, 512),
         out_specs=pl.BlockSpec((bh, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((hp, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bh, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(w_fp8, x)
     return out[:h, 0]

@@ -415,9 +415,8 @@ def main() -> None:
                          "cells (methodology changes) without the full "
                          "compile")
     ap.add_argument("--cache-dir", default=None, metavar="DIR",
-                    help="persistent warm-start directory (XLA compile "
-                         "cache + resolved-lane snapshot); also via "
-                         "REPRO_CACHE_DIR")
+                    help="resolved-lane snapshot directory (warm "
+                         "start); also via REPRO_CACHE_DIR")
     args = ap.parse_args()
     # Registry-backed validation (underscore aliases resolve; unknown
     # names fail with the full menu) instead of frozen argparse choices.
@@ -431,11 +430,11 @@ def main() -> None:
         ap.error(str(e))
 
     from repro.core import warmstart
+    warmstart.enable_compilation_cache()
     warm = warmstart.enable_warm_start(args.cache_dir)
     if warm["cache_dir"]:
         print(f"warm start: cache-dir {warm['cache_dir']} "
-              f"(compile cache {'on' if warm['compile_cache'] else 'off'}, "
-              f"{warm['lanes']} lanes loaded)", flush=True)
+              f"({warm['lanes']} lanes loaded)", flush=True)
 
     if args.pim:
         if not args.all and args.arch not in ARCHS:

@@ -5,6 +5,11 @@ is the primary end-to-end driver: it serves a model with batched
 requests and reports, per decode step, what the LP5X-PIM offload would
 deliver on the reference LPDDR5X-9600 x 4ch memory system.
 
+By default it serves the reduced same-family model in float32
+(``--smoke``); ``--no-smoke`` serves ``--arch`` at its published widths
+with bfloat16 parameters — ``--arch gemma3-4b --no-smoke`` is the largest
+token model of ``configs/`` that one 16 GB TPU v5e chip holds.
+
 With ``--scenario`` the launcher becomes the closed-loop policy testbed:
 a seeded workload (steady / bursty / diurnal / prefill-heavy /
 drain-refill) drives the engine end to end under an adaptive offload
@@ -41,6 +46,7 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, smoke_config
@@ -228,10 +234,20 @@ def run_daemon_mode(args, full_cfg, cfg, params, mesh=None) -> None:
           f"ticks={rep['ticks']},unhandled=0", flush=True)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict | None:
+    """Parse ``argv`` (default: the command line) and serve.
+
+    The plain request path returns ``{"cfg", "params", "requests",
+    "stats"}`` so an in-process caller can check what was served; the
+    scenario and daemon paths return None.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b", choices=list(ARCHS))
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced same-family model in float32 "
+                         "(default); --no-smoke serves --arch at its "
+                         "published widths with bfloat16 parameters")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
@@ -305,15 +321,18 @@ def main() -> None:
                          "--xla_force_host_platform_device_count=N); "
                          "default: threaded multi-device dispatch")
     ap.add_argument("--cache-dir", default=None, metavar="DIR",
-                    help="persistent warm-start directory (XLA compile "
-                         "cache + resolved-lane snapshot); also via "
-                         "REPRO_CACHE_DIR")
+                    help="resolved-lane snapshot directory (warm "
+                         "start); also via REPRO_CACHE_DIR.  The XLA "
+                         "compile cache lives in JAX_COMPILATION_CACHE_DIR "
+                         "or <repo>/.jax_cache")
     ap.add_argument("--lane-backend", default=None,
                     choices=["scan", "pallas", "auto"],
                     help="lane resolver backend (default: "
-                         "REPRO_LANE_BACKEND env or scan); pallas/auto "
-                         "fall back to scan when unsupported")
-    args = ap.parse_args()
+                         "REPRO_LANE_BACKEND env or scan); auto picks "
+                         "from the platform, pallas fails with the "
+                         "compiler's reason where the kernel cannot be "
+                         "built")
+    args = ap.parse_args(argv)
     if args.chaos and not args.scenario:
         args.scenario = "chaos"
     if args.daemon:
@@ -340,19 +359,29 @@ def main() -> None:
         ap.error(str(e))
 
     t_start = time.perf_counter()
+    warmstart.enable_compilation_cache()
     lane_engine.configure_lane_backend(args.lane_backend)
+    # Resolving here builds an explicitly requested pallas kernel, so a
+    # platform where it cannot be built fails now, with the compiler's
+    # reason, before any model work.
+    print(f"lane backend: {lane_engine.resolved_lane_backend()} "
+          f"(requested {lane_engine.lane_backend()})", flush=True)
     warm = warmstart.enable_warm_start(args.cache_dir)
     if warm["cache_dir"]:
         print(f"warm start: cache-dir {warm['cache_dir']} "
-              f"(compile cache {'on' if warm['compile_cache'] else 'off'}, "
-              f"{warm['lanes']} lanes loaded)", flush=True)
+              f"({warm['lanes']} lanes loaded)", flush=True)
 
     full_cfg = ARCHS[args.arch]
     cfg = smoke_config(full_cfg) if args.smoke else full_cfg
     if cfg.input_mode != "tokens":
         raise SystemExit(f"{cfg.name} serves stub embeddings; "
                          "see launch/dryrun.py for its decode cells")
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    # The smoke model keeps float32 (every golden trace is pinned to
+    # it); published widths are served in bfloat16, which is what lets
+    # a 4B model fit one 16 GB chip.
+    params = M.init_params(cfg, jax.random.PRNGKey(0),
+                           dtype=jnp.float32 if args.smoke
+                           else jnp.bfloat16)
 
     mesh = None
     if args.mesh is not None:
@@ -363,13 +392,13 @@ def main() -> None:
     if args.daemon:
         run_daemon_mode(args, full_cfg, cfg, params, mesh=mesh)
         _warm_epilogue(args)
-        return
+        return None
 
     if args.scenario:
         run_scenario_mode(args, full_cfg, cfg, params, mesh=mesh,
                           t_start=t_start)
         _warm_epilogue(args)
-        return
+        return None
 
     # Offload plan computed against the FULL architecture (the simulator
     # works on real matrix sizes regardless of the smoke model we run).
@@ -387,10 +416,12 @@ def main() -> None:
                             planner=planner)
     rng = np.random.default_rng(0)
     frac = 1.0 if args.slo is None else args.slo
+    reqs = []
     for i in range(args.requests):
         req = Request(rid=i,
                       prompt=rng.integers(0, cfg.vocab, size=4 + i % 8),
                       max_new=args.max_new)
+        reqs.append(req)
         if disagg:
             eng.submit(req, slo=(SLO_LATENCY if rng.random() < frac
                                  else SLO_THROUGHPUT))
@@ -412,6 +443,7 @@ def main() -> None:
     print(f"  speedup {tel['speedup']:.2f}x; offloaded "
           f"{len(tel['offloaded'])}/{tel['n_sites']} GEMV sites")
     _warm_epilogue(args)
+    return dict(cfg=cfg, params=params, requests=reqs, stats=stats)
 
 
 def _warm_epilogue(args) -> None:

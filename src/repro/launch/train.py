@@ -42,18 +42,10 @@ def main() -> None:
     ap.add_argument("--restore", action="store_true")
     ap.add_argument("--simulate-failure", action="store_true",
                     help="drill: drop a host mid-run, re-plan, restore")
-    ap.add_argument("--cache-dir", default=None, metavar="DIR",
-                    help="persistent XLA compile-cache directory (skips "
-                         "step-function recompilation across runs); also "
-                         "via REPRO_CACHE_DIR")
     args = ap.parse_args()
 
     from repro.core import warmstart
-    warm = warmstart.enable_warm_start(args.cache_dir)
-    if warm["cache_dir"]:
-        print(f"warm start: cache-dir {warm['cache_dir']} "
-              f"(compile cache {'on' if warm['compile_cache'] else 'off'})",
-              flush=True)
+    warmstart.enable_compilation_cache()
 
     cfg = ARCHS[args.arch]
     if args.smoke:

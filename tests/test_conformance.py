@@ -31,6 +31,7 @@ from repro.core.timing import (DEFAULT_SYSTEM, LpddrTimings, PimSpec,
                                SystemSpec)
 from repro.pimkernel.executor import (FunctionalGemv, GemvRequest,
                                       PimExecutor)
+from repro.pimkernel.grids import golden_record, golden_requests
 from repro.pimkernel.tileconfig import PimDType
 
 from test_engine import build_valid_stream, random_op_tuples
@@ -221,34 +222,10 @@ def test_functional_batch_multi_spec():
 # Golden parity: committed fixtures pin the PR-1 cycle/energy numbers
 # ---------------------------------------------------------------------
 
-GOLDEN_SPECS = {
-    "lp5x-9600": DEFAULT_SYSTEM,
-    "rcd24-mac2": SystemSpec(timings=LpddrTimings(tRCD=24.0),
-                             pim=PimSpec(mac_interval_ck=2)),
-}
-GOLDEN_SHAPES = [("pim", 256, 1024, PimDType.W8A8, False, False),
-                 ("pim", 512, 2048, PimDType.W8A16, True, False),
-                 ("pim", 1024, 512, PimDType.W4A8, False, True),
-                 ("base", 1024, 1024, PimDType.W8A8, False, False)]
-
-
-def _golden_requests():
-    return [(f"{sname}/{kind}-{h}x{w}-{dt.name}"
-             + ("-fence" if f else "") + ("-reshape" if r else ""),
-             GemvRequest.pim(h, w, dt, fence=f, reshape=r, spec=sp)
-             if kind == "pim" else GemvRequest.baseline(h, w, dt, spec=sp))
-            for sname, sp in GOLDEN_SPECS.items()
-            for (kind, h, w, dt, f, r) in GOLDEN_SHAPES]
-
-
 def _snapshot():
-    labels, reqs = zip(*_golden_requests())
+    labels, reqs = zip(*golden_requests())
     results = PimExecutor().run_many(list(reqs))
-    return {label: dict(cycles=res.cycles, ns=res.ns, flops=res.flops,
-                        weight_bytes=res.weight_bytes,
-                        utilization=res.utilization, split=res.split,
-                        counts=[int(c) for c in res.counts],
-                        energy=res.energy)
+    return {label: golden_record(res)
             for label, res in zip(labels, results)}
 
 

@@ -286,3 +286,26 @@ def test_compilations_bounded_by_length_buckets():
     for s in streams.values():          # same buckets again -> no compile
         engine.resolve_fleet([(cyc, [s])])
     assert engine.compile_cache_size() == warm
+
+
+def test_slab_device_rows_count_where_slabs_ran():
+    """Every resolved slab adds its padded width to the device whose
+    result shards hold it — the record ``chip_smoke.py --four-chips``
+    prints to show each device did its share."""
+    rng = np.random.default_rng(41)
+    cyc = DEFAULT_SYSTEM.derive_cycles()
+    lanes = [(cyc, build_valid_stream(random_op_tuples(rng, max_ops=20)))
+             for _ in range(3)]
+    engine.lane_cache_reset()
+    before = engine.slab_device_rows()
+    engine.resolve_lanes(lanes, need_issue=False)
+    after = engine.slab_device_rows()
+    grown = {d: n - before.get(d, 0) for d, n in after.items()
+             if n != before.get(d, 0)}
+    assert set(grown) <= {str(d) for d in engine.lane_devices()}
+    groups: dict[int, int] = {}
+    for _, s in lanes:
+        b = engine._length_bucket(s.shape[0])
+        groups[b] = groups.get(b, 0) + 1
+    assert sum(grown.values()) == sum(engine._fleet_bucket(n)
+                                      for n in groups.values())

@@ -78,6 +78,25 @@ def test_gemm_fp_matches_ref():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("batched", [False, True])
+def test_interpret_none_follows_platform(batched):
+    """``interpret=None`` (the default) is ``ops.default_interpret()``:
+    the interpreter here, native compilation on TPU — same numbers as an
+    explicit ``interpret=True`` off TPU."""
+    assert ops.default_interpret() == (jax.default_backend() != "tpu")
+    rng = np.random.default_rng(5)
+    w8 = jnp.asarray(rng.standard_normal((128, 256)) * 0.5,
+                     jnp.float32).astype(jnp.float8_e4m3fn)
+    x = jnp.asarray(rng.standard_normal((3, 256)) * 0.5, jnp.bfloat16)
+    if batched:
+        got = pim_gemm_fp(w8, x, block=(8, 128, 256))
+        want = pim_gemm_fp(w8, x, block=(8, 128, 256), interpret=True)
+    else:
+        got = pim_gemv_fp(w8, x[0], block=BLOCK)
+        want = pim_gemv_fp(w8, x[0], block=BLOCK, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_pack_unpack_roundtrip():
     rng = np.random.default_rng(0)
     q = rng.integers(-8, 8, size=(16, 64)).astype(np.int8)

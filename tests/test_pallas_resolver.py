@@ -4,15 +4,16 @@ The Pallas kernel (``kernels/lane_scan.py``) is the fourth lane backend
 (single-device scan, threaded multi-device, ``shard_map`` mesh, Pallas).
 Its contract is bit-identity with the scan resolver — and therefore with
 ``RefEngine`` — on every lane, plus clean selection semantics:
-``configure_lane_backend``/``REPRO_LANE_BACKEND`` pick it, capability
-probing falls back to scan instead of breaking resolution, and the
-engine's dedupe/LRU/slab plumbing is backend-oblivious.  The scan-unroll
+``configure_lane_backend``/``REPRO_LANE_BACKEND`` pick it, ``auto``
+follows the platform rule, an explicit ``pallas`` request that cannot be
+built raises instead of running another backend, and the engine's
+dedupe/LRU/slab plumbing is backend-oblivious.  The scan-unroll
 satellite lives here too: unroll={1,2,4,8} must be bit-identical.
 """
 import numpy as np
 import pytest
 
-from repro.core import engine
+from repro.core import engine, faults
 from repro.core.engine_ref import RefEngine
 from repro.core.timing import DEFAULT_SYSTEM
 
@@ -21,6 +22,7 @@ from test_engine import build_valid_stream, random_op_tuples
 
 from repro.kernels import lane_scan
 
+# The platform rule (no kernel is built while the module is imported).
 PALLAS_OK = lane_scan.pallas_lane_supported()
 needs_pallas = pytest.mark.skipif(
     not PALLAS_OK, reason="pallas lane resolver unsupported here")
@@ -69,9 +71,9 @@ def test_pallas_resolver_direct_matches_ref():
     streams = [build_valid_stream(random_op_tuples(rng, max_ops=24))
                for _ in range(3)]
     n = max(s.shape[0] for s in streams)
-    batch = np.zeros((len(streams), n, 4), dtype=np.int32)
+    batch = np.zeros((len(streams), 4, n), dtype=np.int32)
     for i, s in enumerate(streams):
-        batch[i, : s.shape[0]] = s
+        batch[i, :, : s.shape[0]] = s.T
     cycs = engine.stack_cycles([cyc] * len(streams))
     issue, total = lane_scan.make_lane_resolver(cyc.num_banks)(cycs, batch)
     ref = RefEngine(cyc, validate=False)
@@ -83,7 +85,7 @@ def test_pallas_resolver_direct_matches_ref():
 
 
 # ---------------------------------------------------------------------
-# Selection semantics: config > env > default, with capability fallback
+# Selection semantics: config > env > default; no hidden fallback
 # ---------------------------------------------------------------------
 
 def test_backend_config_precedence(monkeypatch):
@@ -114,15 +116,30 @@ def test_backend_scope_restores_on_error(monkeypatch):
 
 
 def test_pallas_falls_back_to_scan_when_unsupported(monkeypatch):
-    """An unsupported probe must degrade pallas/auto to the scan path —
-    resolution keeps working, nothing raises."""
+    """It no longer does: where the kernel cannot be built, an explicit
+    pallas request raises the compiler's reason — from the backend query
+    and from resolution alike — and never runs the scan path instead,
+    while ``auto`` follows the platform rule to scan."""
+    def refuse(num_banks, unroll=None, interpret=None):
+        raise NotImplementedError("Mosaic: no lowering for scan")
+
+    lane_scan.require_lane_kernel.cache_clear()
+    monkeypatch.setattr(lane_scan, "make_lane_resolver", refuse)
     monkeypatch.setattr(lane_scan, "pallas_lane_supported", lambda: False)
-    with engine.lane_backend_scope("pallas"):
-        assert engine.resolved_lane_backend() == "scan"
-        lanes = _lanes(0, n=2, max_ops=12)
-        engine.lane_cache_reset()
-        res = engine.resolve_lanes(lanes)
-    assert len(res) == 2
+    lanes = _lanes(0, n=2, max_ops=12)
+    try:
+        with engine.lane_backend_scope("pallas"):
+            with pytest.raises(NotImplementedError, match="Mosaic"):
+                engine.resolved_lane_backend()
+            engine.lane_cache_reset()
+            with pytest.raises(NotImplementedError, match="Mosaic"):
+                engine.resolve_lanes(lanes)
+        assert not [e for e in faults.events() if e["kind"] == "degrade"]
+        with engine.lane_backend_scope("auto"):
+            assert engine.resolved_lane_backend() == "scan"
+            assert len(engine.resolve_lanes(lanes)) == 2
+    finally:
+        lane_scan.require_lane_kernel.cache_clear()
 
 
 @needs_pallas

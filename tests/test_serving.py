@@ -141,3 +141,64 @@ def test_offload_reshape_regime_for_moe():
     plan = planner.plan()
     small = [d for d in plan if d.site.h < 2048]
     assert small and all(d.reshape for d in small)
+
+
+# ---------------------------------------------------------------------
+# serve launcher: --smoke is a real switch; no hidden backend fallback
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def tiny_arch(monkeypatch, tmp_path):
+    """A smoke-sized architecture registered under its own name, so
+    ``--no-smoke`` serves it "at its published widths" in seconds; the
+    compile cache is pointed at an unset directory so the launcher turns
+    on no persistent cache inside the test process."""
+    import dataclasses
+
+    from jax.experimental.compilation_cache import compilation_cache
+    cfg = dataclasses.replace(smoke_config(ARCHS["gemma3-4b"]),
+                              name="tiny-gemma")
+    monkeypatch.setitem(ARCHS, "tiny-gemma", cfg)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    names = ("jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield cfg
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_serve_no_smoke_serves_bf16_published_widths(tiny_arch):
+    from repro.launch import serve
+    out = serve.main(["--arch", "tiny-gemma", "--no-smoke",
+                      "--requests", "3", "--slots", "2", "--max-new", "3"])
+    assert out["cfg"] is tiny_arch
+    assert {str(a.dtype) for a in jax.tree.leaves(out["params"])} == \
+        {"bfloat16"}
+    assert all(r.done and len(r.out) == 3 for r in out["requests"])
+    smoke = serve.main(["--arch", "tiny-gemma", "--requests", "1",
+                        "--max-new", "2"])
+    assert smoke["cfg"] == smoke_config(tiny_arch)
+    assert {str(a.dtype) for a in jax.tree.leaves(smoke["params"])} == \
+        {"float32"}
+
+
+def test_serve_pallas_request_fails_with_compiler_reason(tiny_arch,
+                                                         monkeypatch):
+    """Where the lane kernel cannot be built, ``--lane-backend pallas``
+    raises the compiler's reason before serving anything."""
+    from repro.kernels import lane_scan
+    from repro.launch import serve
+
+    def refuse(num_banks, unroll=None, interpret=None):
+        raise NotImplementedError("Mosaic: no lowering for scan")
+
+    lane_scan.require_lane_kernel.cache_clear()
+    monkeypatch.setattr(lane_scan, "make_lane_resolver", refuse)
+    try:
+        with pytest.raises(NotImplementedError, match="Mosaic"):
+            serve.main(["--arch", "tiny-gemma", "--lane-backend", "pallas",
+                        "--requests", "1", "--max-new", "2"])
+    finally:
+        lane_scan.require_lane_kernel.cache_clear()

@@ -273,7 +273,7 @@ def test_rejected_snapshot_records_detect_event(tmp_path):
 def test_enable_warm_start_no_dir_is_noop(monkeypatch):
     monkeypatch.delenv("REPRO_CACHE_DIR", raising=False)
     rep = warmstart.enable_warm_start()
-    assert rep == {"cache_dir": None, "compile_cache": False, "lanes": 0}
+    assert rep == {"cache_dir": None, "lanes": 0}
     assert warmstart.save_warm_start() == -1
 
 
@@ -307,6 +307,52 @@ def test_import_disabled_cache_keeps_nothing(tmp_path):
         assert warmstart.load_lane_snapshot(str(tmp_path)) == 0
     finally:
         engine.configure_lane_cache(4096)
+
+
+# ---------------------------------------------------------------------
+# XLA compile cache: placed from outside, else one fixed in-checkout path
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def _restore_compile_cache_config():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield jax.config
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_honours_env_dir(tmp_path, monkeypatch,
+                                       _restore_compile_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache
+    and the code sets no directory of its own."""
+    config = _restore_compile_cache_config
+    before = config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    assert warmstart.enable_compilation_cache() == str(tmp_path / "xla")
+    assert config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "xla").exists()      # nothing created by us
+
+
+def test_compile_cache_fixed_checkout_path_when_unset(
+        monkeypatch, _restore_compile_cache_config):
+    """Unset, the cache sits at one fixed path inside the checkout — the
+    same path every call and every process, never a temp directory and
+    never tied to ``--cache-dir``."""
+    config = _restore_compile_cache_config
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("REPRO_CACHE_DIR", "/nonexistent-lane-dir")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert warmstart.enable_compilation_cache() == want
+    assert config.jax_compilation_cache_dir == want
+    assert config.jax_persistent_cache_min_compile_time_secs == 0
+    assert warmstart.enable_compilation_cache() == want   # stable
 
 
 # ---------------------------------------------------------------------
