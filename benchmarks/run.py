@@ -9,7 +9,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   fleet/*      — planning/resolution split, batched vs looped sweeps,
                  serve-replan lane-cache rows (fleet API)
   offload/*    — LLM decode offload case study (framework layer)
-  roofline/*   — dominant term + roofline fraction per dry-run cell
 """
 from __future__ import annotations
 
@@ -21,8 +20,7 @@ force_host_devices()
 
 
 def main() -> None:
-    from . import energy_fig, engine_speed, fleet_speed, paper_figs, \
-        roofline
+    from . import energy_fig, engine_speed, fleet_speed, paper_figs
 
     paper_figs.main()
     engine_speed.main()
@@ -39,11 +37,6 @@ def main() -> None:
         tel = OffloadPlanner(ARCHS[arch], sim).decode_speedup(batch=1)
         print(f"offload/{arch}/b1,{tel['mixed_ns']/1e3:.1f},"
               f"{tel['speedup']:.3f}")
-
-    try:
-        roofline.main()
-    except Exception as e:  # dry-run artifacts may not exist yet
-        print(f"roofline/unavailable,0,{type(e).__name__}")
 
 
 if __name__ == "__main__":

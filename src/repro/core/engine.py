@@ -35,6 +35,7 @@ import numpy as np
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from repro import obs
 from . import commands as C
 from . import faults
 from .timing import TimingCycles
@@ -1132,63 +1133,72 @@ def resolve_lanes(
     mesh, devices AND circuit breaker); defaults to the active scope,
     so cells that activate their scope with :class:`backend_scope` need
     not pass it explicitly.
+
+    Program spans (``repro.obs``): ``sim.lookup`` around the dedupe and
+    LRU pass; per slab, on every rung, ``sim.pack`` (packing, configs,
+    ``device_put``) and ``sim.resolve`` (resolver call, readback, store;
+    ``commands`` is the slab's unpadded command count).
     """
     scope = active_backend_scope() if scope is None else scope
     lanes = list(lanes)
-    uniq: list[list] = []              # [cyc, stream, ukey]
-    lane_of: list[int] = []            # flat lane -> unique lane
-    uniq_index: dict = {}
-    for i, (cyc, s) in enumerate(lanes):
-        k = keys[i] if keys is not None else None
-        if k is not None:
-            ukey = (cyc, 0, k)
-        else:
+    with obs.span("sim.lookup", lanes=len(lanes)) as sp:
+        uniq: list[list] = []              # [cyc, stream, ukey]
+        lane_of: list[int] = []            # flat lane -> unique lane
+        uniq_index: dict = {}
+        for i, (cyc, s) in enumerate(lanes):
+            k = keys[i] if keys is not None else None
+            if k is not None:
+                ukey = (cyc, 0, k)
+            else:
+                s = np.ascontiguousarray(s, dtype=np.int32)
+                ukey = (cyc, 1, s.shape[0],
+                        hashlib.blake2b(s.tobytes(),
+                                        digest_size=16).digest())
+            u = uniq_index.get(ukey)
+            if u is None:
+                u = len(uniq)
+                uniq_index[ukey] = u
+                uniq.append([cyc, s, ukey])
+            lane_of.append(u)
+
+        issues: list[np.ndarray | None] = [None] * len(uniq)
+        totals = np.zeros(len(uniq), dtype=np.int32)
+        misses: list[int] = []
+        for u, (cyc, s, ukey) in enumerate(uniq):
+            ent = _lane_cache_get(ukey, need_issue)
+            if ent is not None:
+                totals[u] = ent[0]
+                issues[u] = ent[1] if need_issue else None
+            else:
+                misses.append(u)
+
+        # Second-level dedupe of the misses by byte identity; ``todo``
+        # holds one representative per distinct (config, bytes),
+        # ``alias`` the cache-key lanes that share its result.
+        todo: list[int] = []
+        alias: dict[int, list[int]] = {}
+        hash_index: dict = {}
+        for u in misses:
+            cyc, s, _ukey = uniq[u]
             s = np.ascontiguousarray(s, dtype=np.int32)
-            ukey = (cyc, 1, s.shape[0],
+            uniq[u][1] = s
+            hkey = (cyc, s.shape[0],
                     hashlib.blake2b(s.tobytes(), digest_size=16).digest())
-        u = uniq_index.get(ukey)
-        if u is None:
-            u = len(uniq)
-            uniq_index[ukey] = u
-            uniq.append([cyc, s, ukey])
-        lane_of.append(u)
+            rep = hash_index.get(hkey)
+            if rep is None:
+                hash_index[hkey] = u
+                todo.append(u)
+                alias[u] = []
+            else:
+                alias[rep].append(u)
 
-    issues: list[np.ndarray | None] = [None] * len(uniq)
-    totals = np.zeros(len(uniq), dtype=np.int32)
-    misses: list[int] = []
-    for u, (cyc, s, ukey) in enumerate(uniq):
-        ent = _lane_cache_get(ukey, need_issue)
-        if ent is not None:
-            totals[u] = ent[0]
-            issues[u] = ent[1] if need_issue else None
-        else:
-            misses.append(u)
-
-    # Second-level dedupe of the misses by byte identity; ``todo`` holds
-    # one representative per distinct (config, bytes), ``alias`` the
-    # cache-key lanes that share its result.
-    todo: list[int] = []
-    alias: dict[int, list[int]] = {}
-    hash_index: dict = {}
-    for u in misses:
-        cyc, s, _ukey = uniq[u]
-        s = np.ascontiguousarray(s, dtype=np.int32)
-        uniq[u][1] = s
-        hkey = (cyc, s.shape[0],
-                hashlib.blake2b(s.tobytes(), digest_size=16).digest())
-        rep = hash_index.get(hkey)
-        if rep is None:
-            hash_index[hkey] = u
-            todo.append(u)
-            alias[u] = []
-        else:
-            alias[rep].append(u)
-
-    groups: dict[tuple[int, int], list[int]] = {}
-    for u in todo:
-        cyc, s, _ukey = uniq[u]
-        groups.setdefault((cyc.num_banks, _length_bucket(s.shape[0])),
-                          []).append(u)
+        groups: dict[tuple[int, int], list[int]] = {}
+        for u in todo:
+            cyc, s, _ukey = uniq[u]
+            groups.setdefault(
+                (cyc.num_banks, _length_bucket(s.shape[0])), []).append(u)
+        sp.set(unique=len(uniq), hits=len(uniq) - len(misses),
+               misses=len(misses), todo=len(todo))
 
     done: dict[int, bool] = {u: False for u in todo}
 
@@ -1212,6 +1222,31 @@ def resolve_lanes(
                 _lane_cache_put(uniq[v][2], int(tot[row]), issues[u])
             done[u] = True
 
+    def _pack(chunk: list[int], width: int, length: int, target):
+        """One slab: the chunk's lanes packed into a pooled buffer and
+        padded to ``width`` rows, then placed on ``target`` (a device or
+        a sharding).  Returns the buffer, the placed (configs, slab) and
+        the chunk's true command count."""
+        with obs.span("sim.pack", width=width, length=length):
+            buf = _take_slab(width, length)
+            commands = 0
+            for row, u in enumerate(chunk):
+                s = uniq[u][1]
+                buf[row, :, : s.shape[0]] = s.T
+                commands += s.shape[0]
+            cycs = [uniq[u][0] for u in chunk]
+            cycs += [cycs[0]] * (width - len(chunk))
+            placed = (jax.device_put(stack_cycles(cycs), target),
+                      jax.device_put(buf, target))
+        return buf, placed, commands
+
+    def _resolve(resolver, chunk: list[int], placed, commands: int) -> None:
+        with obs.span("sim.resolve", commands=commands):
+            iss, tot = resolver(*placed)
+            _count_slab_rows(tot)
+            tot = np.asarray(tot)
+            _store(chunk, np.asarray(iss) if need_issue else None, tot)
+
     def _pending_groups() -> dict[tuple[int, int], list[int]]:
         return {gk: left for gk, idxs in sorted(groups.items())
                 if (left := [u for u in idxs if not done[u]])}
@@ -1228,19 +1263,9 @@ def resolve_lanes(
         for (nb, length), idxs in _pending_groups().items():
             for lo in range(0, len(idxs), _MAX_WIDTH * m):
                 chunk = idxs[lo:lo + _MAX_WIDTH * m]
-                width = _mesh_width(len(chunk), m)
-                buf = _take_slab(width, length)
-                for row, u in enumerate(chunk):
-                    s = uniq[u][1]
-                    buf[row, :, : s.shape[0]] = s.T
-                cycs = [uniq[u][0] for u in chunk]
-                cycs += [cycs[0]] * (width - len(chunk))
-                placed = (jax.device_put(stack_cycles(cycs), sharding),
-                          jax.device_put(buf, sharding))
-                iss, tot = _mesh_resolver(nb, mesh)(*placed)
-                _count_slab_rows(tot)
-                tot = np.asarray(tot)
-                _store(chunk, np.asarray(iss) if need_issue else None, tot)
+                buf, placed, commands = _pack(
+                    chunk, _mesh_width(len(chunk), m), length, sharding)
+                _resolve(_mesh_resolver(nb, mesh), chunk, placed, commands)
                 _give_slab(buf)
 
     def _run_sharded(resolver, devs) -> None:
@@ -1271,25 +1296,14 @@ def resolve_lanes(
         borrowed: list[np.ndarray] = []
         per_dev: list[list] = [[] for _ in devs]
         for i, (nb, chunk, width, length) in enumerate(slabs):
-            buf = _take_slab(width, length)
-            for row, u in enumerate(chunk):
-                s = uniq[u][1]
-                buf[row, :, : s.shape[0]] = s.T
-            cycs = [uniq[u][0] for u in chunk]
-            cycs += [cycs[0]] * (width - len(chunk))
-            dev = devs[assignment[i]]
-            placed = (jax.device_put(stack_cycles(cycs), dev),
-                      jax.device_put(buf, dev))
+            buf, placed, commands = _pack(chunk, width, length,
+                                          devs[assignment[i]])
             borrowed.append(buf)
-            per_dev[assignment[i]].append((nb, chunk, placed))
+            per_dev[assignment[i]].append((nb, chunk, placed, commands))
 
         def _run_dev(jobs) -> None:
-            for nb, chunk, (cycs, batch) in jobs:
-                iss, tot = resolver(nb)(cycs, batch)
-                _count_slab_rows(tot)
-                tot = np.asarray(tot)
-                _store(chunk, np.asarray(iss) if need_issue else None,
-                       tot)
+            for nb, chunk, placed, commands in jobs:
+                _resolve(resolver(nb), chunk, placed, commands)
 
         act = [jobs for jobs in per_dev if jobs]
         if len(act) <= 1:

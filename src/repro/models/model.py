@@ -530,6 +530,24 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos):
     return (x @ _head_matrix(cfg, params))[:, 0], new_cache
 
 
+def _jit_named(fn, name: str):
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def jit_prefill(cfg: ArchConfig):
+    """``prefill`` of ``cfg``, jitted as ``(params, batch, cache)``; its XLA
+    module is named ``jit_prefill`` in a trace."""
+    return _jit_named(lambda p, b, c: prefill(cfg, p, b, c), "prefill")
+
+
+def jit_decode_step(cfg: ArchConfig):
+    """``decode_step`` of ``cfg``, jitted as ``(params, cache, token, pos)``;
+    its XLA module is named ``jit_decode_step`` in a trace."""
+    return _jit_named(lambda p, c, t, pos: decode_step(cfg, p, c, t, pos),
+                      "decode_step")
+
+
 # ---------------------------------------------------------------------
 # Dry-run input specs (no allocation)
 # ---------------------------------------------------------------------

@@ -29,6 +29,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core import commands as C
 from repro.core import controller, device, engine
 from repro.core.energy import EnergyParams, gemv_energy_summary
@@ -277,18 +278,25 @@ class PimExecutor:
         are bit-identical to the per-call ``run_gemv`` / ``run_baseline``
         paths under each request's spec.
         """
-        reqs = [r.resolved(self.default_spec) for r in reqs]
-        uniq: dict[tuple, GemvRequest] = {}
-        for r in reqs:
-            uniq.setdefault(r.key, r)
-        planned = self.plan_many(uniq.values())
-        fleet = engine.resolve_fleet(
-            [(p.ctx.cyc, p.streams) for p in planned],
-            keys=[p.stream_keys for p in planned],
-            need_issue=False)
-        by_key = {p.req.key: self._finish(p, fr.totals)
-                  for p, fr in zip(planned, fleet)}
-        return [by_key[r.key] for r in reqs]
+        with obs.span("sim.run_many", requests=len(reqs)) as sp:
+            reqs = [r.resolved(self.default_spec) for r in reqs]
+            uniq: dict[tuple, GemvRequest] = {}
+            for r in reqs:
+                uniq.setdefault(r.key, r)
+            sp.set(unique=len(uniq))
+            with obs.span("sim.plan") as pl:
+                planned = self.plan_many(uniq.values())
+                if obs.on():
+                    pl.set(streams=sum(len(p.streams) for p in planned),
+                           commands=sum(len(st) for p in planned
+                                        for st in p.streams))
+            fleet = engine.resolve_fleet(
+                [(p.ctx.cyc, p.streams) for p in planned],
+                keys=[p.stream_keys for p in planned],
+                need_issue=False)
+            by_key = {p.req.key: self._finish(p, fr.totals)
+                      for p, fr in zip(planned, fleet)}
+            return [by_key[r.key] for r in reqs]
 
     def run_functional_many(self, items: Sequence[FunctionalGemv]
                             ) -> list[tuple[np.ndarray, PimResult]]:

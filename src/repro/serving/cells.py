@@ -236,8 +236,7 @@ class PrefillCell:
         self.shed: dict[int, int] = {}    # rid -> shed tick
         # Jitted like the decode cell's step: one compile per prompt
         # length keeps a budget-6 prefill tick in the milliseconds.
-        self._prefill_fn = jax.jit(
-            lambda p, b, c: M.prefill(cfg, p, b, c))
+        self._prefill_fn = M.jit_prefill(cfg)
 
     def submit(self, req: Request, slo: str, tick: int) -> None:
         self.queue.push(req, slo, tick)
@@ -313,7 +312,7 @@ class DecodeCell:
     def __init__(self, cfg: ArchConfig, params, slots: int, max_seq: int,
                  planner: Optional[OffloadPlanner] = None,
                  controller: Optional[OffloadController] = None,
-                 step_telemetry: bool = False, spec_decode=None,
+                 spec_decode=None,
                  scope: "lane_engine.BackendScope | None" = None):
         assert cfg.input_mode == "tokens", "cells serve token models"
         self.cfg, self.params = cfg, params
@@ -335,8 +334,6 @@ class DecodeCell:
         self.stats = dict(steps=0, tokens=0)
         self.batch_occupancy: dict[int, int] = {}
         self.step_batches: list[int] = []
-        self.step_telemetry = step_telemetry
-        self.step_speedups: list[dict] = []
         self.admit_ticks: dict[int, int] = {}
         self.completions: dict[int, int] = {}
         # Speculative decoding: same seeded accept/advance schedule as
@@ -347,8 +344,7 @@ class DecodeCell:
         self.spec_accepted: dict[int, int] = {}
         self.spec_advance: list[int] = []
         self.spec_substeps: list[int] = []
-        self._decode = jax.jit(
-            lambda p, c, t, pos: M.decode_step(cfg, p, c, t, pos))
+        self._decode = M.jit_decode_step(cfg)
 
     def free_slots(self) -> int:
         return sum(1 for r in self.active if r is None)
@@ -407,11 +403,6 @@ class DecodeCell:
         self.step_batches.append(len(act))
         if self.controller is not None:
             self.controller.observe(len(act))
-        if self.planner is not None and self.step_telemetry:
-            tel = self.planner.decode_speedup(batch=len(act))
-            self.step_speedups.append(dict(step=self.stats["steps"],
-                                           batch=len(act),
-                                           speedup=tel["speedup"]))
         self.stats["steps"] += 1
         return len(act)
 
@@ -484,7 +475,7 @@ class DisaggServingEngine:
                  planner: Optional[OffloadPlanner] = None,
                  controller: Optional[OffloadController] = None,
                  prefill_controller: Optional[OffloadController] = None,
-                 step_telemetry: bool = False, spec_decode=None,
+                 spec_decode=None,
                  prefill_scope: "lane_engine.BackendScope | None" = None,
                  decode_scope: "lane_engine.BackendScope | None" = None):
         self.disagg = disagg or DisaggConfig.mirror()
@@ -497,7 +488,6 @@ class DisaggServingEngine:
         self.decode_cell = DecodeCell(cfg, params, slots, max_seq,
                                       planner=planner,
                                       controller=controller,
-                                      step_telemetry=step_telemetry,
                                       spec_decode=spec_decode,
                                       scope=decode_scope)
         self.ticks = 0
@@ -641,8 +631,6 @@ class DisaggServingEngine:
                 tel["occupancy_weighted"] = \
                     dec.planner.occupancy_weighted_speedup(
                         dec.batch_occupancy)
-            if dec.step_speedups:
-                tel["per_step"] = list(dec.step_speedups)
             out["pim_telemetry"] = tel
         if dec.controller is not None:
             out["policy"] = dec.controller.report()

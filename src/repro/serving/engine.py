@@ -6,9 +6,17 @@ decode loop steps every active slot in one batched ``decode_step`` call
 slots (EOS or max tokens) free immediately and the next waiting request
 takes over — continuous batching, the production serving pattern.
 
-The engine also carries the PIM telemetry: per decode step it asks the
-OffloadPlanner what the step would cost on a host-only vs PIM-offloaded
-LPDDR5X system (the paper's motivating use case: on-device LLM decode).
+The engine also carries the PIM telemetry: per decode step its
+``OffloadController`` asks the OffloadPlanner which GEMV sites to run in
+memory at the step's batch, and what the step would cost on a host-only
+vs PIM-offloaded LPDDR5X system (the paper's motivating use case:
+on-device LLM decode).
+
+Program spans (``repro.obs``): ``serve.step`` per call, ``serve.queue``
+per request from submit to admission, ``serve.admit`` per admission
+(``serve.prefill``, ``serve.merge``, ``serve.first_token``), and per
+decode ``serve.h2d``, ``serve.decode``, ``serve.sample``,
+``serve.readback`` and ``serve.plan``.
 
 Speculative decoding (``spec_decode=``, a
 ``scenarios.SpecDecodeConfig``): each serve tick runs one draft/verify
@@ -29,12 +37,14 @@ precedent as inactive slots decoding token 0).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.models import model as M
 from .offload import OffloadPlanner
@@ -53,8 +63,8 @@ class Request:
 
 class ServingEngine:
     def __init__(self, cfg: ArchConfig, params, slots: int = 4,
-                 max_seq: int = 256, planner: Optional[OffloadPlanner]
-                 = None, step_telemetry: bool = False,
+                 max_seq: int = 256,
+                 planner: Optional[OffloadPlanner] = None,
                  controller: Optional[OffloadController] = None,
                  spec_decode=None):
         assert cfg.input_mode == "tokens", "engine serves token models"
@@ -84,12 +94,8 @@ class ServingEngine:
         self.ticks = 0                         # step() calls, idle included
         self.admit_ticks: dict[int, int] = {}
         self.completions: dict[int, int] = {}
-        # Per-step PIM telemetry: one planner query per decode step at
-        # the step's true occupancy.  The first query per batch size does
-        # the (lane-cache-accelerated) fleet resolve; repeats are pure
-        # arithmetic over the cached offload decisions.
-        self.step_telemetry = step_telemetry
-        self.step_speedups: list[dict] = []
+        # rid -> perf_counter_ns at submit, kept only while spans record
+        self._queued: dict[int, int] = {}
         # Speculative decoding: the seeded accept/advance schedule
         # (duck-typed — scenarios.SpecDecodeConfig; None = vanilla) plus
         # per-request round counters and the per-tick advance telemetry
@@ -101,24 +107,29 @@ class ServingEngine:
         self.spec_advance: list[int] = []
         self.spec_substeps: list[int] = []
 
-        self._decode = jax.jit(
-            lambda p, c, t, pos: M.decode_step(cfg, p, c, t, pos))
+        self._decode = M.jit_decode_step(cfg)
         # Jitted like decode: one compile per prompt length, then ~ms
         # per prefill — eager prefill is the serving stack's tick-time
         # ceiling (a daemon admitting tens of requests per tick spends
         # its whole tick in op-by-op dispatch otherwise).
-        self._prefill_fn = jax.jit(
-            lambda p, b, c: M.prefill(cfg, p, b, c))
+        self._prefill_fn = M.jit_prefill(cfg)
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
+        if obs.on():
+            self._queued[req.rid] = time.perf_counter_ns()
         self.waiting.append(req)
 
     def _admit(self, tick: int):
         for slot in range(self.slots):
             if self.active[slot] is None and self.waiting:
                 req = self.waiting.pop(0)
-                self._prefill(slot, req)
+                t0 = self._queued.pop(req.rid, None)
+                if t0 is not None:
+                    obs.interval("serve.queue", t0, rid=req.rid)
+                with obs.span("serve.admit", rid=req.rid, slot=slot,
+                              prompt_len=len(req.prompt)):
+                    self._prefill(slot, req)
                 self.active[slot] = req
                 self.admit_ticks[req.rid] = tick
 
@@ -126,43 +137,45 @@ class ServingEngine:
         """Single-slot prefill into the batched cache (slot-masked)."""
         s = len(req.prompt)
         assert s < self.max_seq
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None]
-        tmp_cache = M.init_cache(self.cfg, 1, self.max_seq, jnp.float32)
-        logits, tmp_cache = self._prefill_fn(self.params,
-                                             {"tokens": prompt}, tmp_cache)
+        with obs.span("serve.prefill"):
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None]
+            tmp_cache = M.init_cache(self.cfg, 1, self.max_seq, jnp.float32)
+            logits, tmp_cache = self._prefill_fn(
+                self.params, {"tokens": prompt}, tmp_cache)
+
         # merge the single-row cache into the batched cache at `slot`
         def merge(full, one):
             return full.at[:, slot:slot + 1].set(one)
-        self.cache = jax.tree.map(merge, self.cache, tmp_cache)
+        with obs.span("serve.merge"):
+            self.cache = jax.tree.map(merge, self.cache, tmp_cache)
         self.pos[slot] = s
-        tok = int(jnp.argmax(logits[0]))
+        with obs.span("serve.first_token"):
+            tok = int(jnp.argmax(logits[0]))
         req.out.append(tok)
         self.stats["prefills"] += 1
 
     # ------------------------------------------------------------------
     def step(self):
         """One batched decode step over all active slots."""
+        with obs.span("serve.step", tick=self.ticks,
+                      waiting=len(self.waiting)) as sp:
+            batch = self._step()
+            sp.set(batch=batch)
+        return batch > 0
+
+    def _step(self) -> int:
         tick = self.ticks
         self.ticks += 1          # idle ticks advance too (driver-aligned)
         self._admit(tick)
         act = [i for i, r in enumerate(self.active) if r is not None]
         if not act:
-            return False
+            return 0
         self.batch_occupancy[len(act)] = \
             self.batch_occupancy.get(len(act), 0) + 1
         if self.spec_decode is not None:
             self._spec_round(tick, act)
         else:
-            tokens = np.zeros((self.slots, 1), dtype=np.int32)
-            for i in act:
-                tokens[i, 0] = self.active[i].out[-1]
-            # one position per slot (ragged decode positions)
-            pos = jnp.asarray(self.pos, jnp.int32)
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(tokens), pos)
-            # one argmax over the whole batch on device, one host
-            # transfer — not a device->host sync per active slot
-            next_tok = np.asarray(jnp.argmax(logits, axis=-1)).reshape(-1)
+            next_tok = self._decode_rows(act)
             for i in act:
                 req = self.active[i]
                 tok = int(next_tok[i])
@@ -176,14 +189,32 @@ class ServingEngine:
                     self.completions[req.rid] = tick
         self.step_batches.append(len(act))
         if self.controller is not None:
-            self.controller.observe(len(act))
-        if self.planner is not None and self.step_telemetry:
-            tel = self.planner.decode_speedup(batch=len(act))
-            self.step_speedups.append(dict(step=self.stats["steps"],
-                                           batch=len(act),
-                                           speedup=tel["speedup"]))
+            with obs.span("serve.plan") as sp:
+                rec = self.controller.observe(len(act))
+                sp.set(offloaded=rec.offloaded)
         self.stats["steps"] += 1
-        return True
+        return len(act)
+
+    def _decode_rows(self, rows: list[int]) -> np.ndarray:
+        """One batched ``decode_step`` that feeds each slot in ``rows``
+        its last token (every other slot token 0); the next token of
+        every slot, read back to the host."""
+        with obs.span("serve.h2d"):
+            tokens = np.zeros((self.slots, 1), dtype=np.int32)
+            for i in rows:
+                tokens[i, 0] = self.active[i].out[-1]
+            # one position per slot (ragged decode positions)
+            pos = jnp.asarray(self.pos, jnp.int32)
+            tokens = jnp.asarray(tokens)
+        with obs.span("serve.decode"):
+            logits, self.cache = self._decode(self.params, self.cache,
+                                              tokens, pos)
+        # one argmax over the whole batch on device, one host transfer —
+        # not a device->host sync per active slot
+        with obs.span("serve.sample"):
+            next_tok = jnp.argmax(logits, axis=-1)
+        with obs.span("serve.readback"):
+            return np.asarray(next_tok).reshape(-1)
 
     def _spec_round(self, tick: int, act: list[int]) -> None:
         """One speculative round per active slot, as batched sub-steps.
@@ -217,14 +248,8 @@ class ServingEngine:
                     if s < adv[i] and self.active[i] is not None]
             if not live:
                 break
-            tokens = np.zeros((self.slots, 1), dtype=np.int32)
-            for i in act:
-                if self.active[i] is not None:
-                    tokens[i, 0] = self.active[i].out[-1]
-            pos = jnp.asarray(self.pos, jnp.int32)
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(tokens), pos)
-            next_tok = np.asarray(jnp.argmax(logits, axis=-1)).reshape(-1)
+            next_tok = self._decode_rows(
+                [i for i in act if self.active[i] is not None])
             for i in live:
                 req = self.active[i]
                 tok = int(next_tok[i])
@@ -289,8 +314,6 @@ class ServingEngine:
                 tel["occupancy_weighted"] = \
                     self.planner.occupancy_weighted_speedup(
                         self.batch_occupancy)
-            if self.step_speedups:
-                tel["per_step"] = list(self.step_speedups)
             out["pim_telemetry"] = tel
         if self.controller is not None:
             out["policy"] = self.controller.report()

@@ -3,9 +3,9 @@
 The ``OffloadPlanner`` answers "which GEMV sites does PIM win at batch
 B?"; this module decides *when that question is asked*.  In a live
 decode loop the batch size shifts every step (requests finish, bursts
-arrive), and per-step recomputation — today's ``step_telemetry``
-behavior — issues one planner query per decode step.  The
-``OffloadController`` wraps the planner behind pluggable policies:
+arrive), and per-step recomputation issues one planner query per
+decode step.  The ``OffloadController`` wraps the planner behind
+pluggable policies:
 
 * ``per-step`` — recompute the oracle offload set every step (the
   baseline and, by construction, the realized-speedup oracle).
