@@ -108,8 +108,8 @@ def cache_shardings(cfg: ArchConfig, mesh, shape: ShapeConfig):
     batch_ax = _batch_dim_axes(mesh, b)
 
     def kv_spec(leaf_shape):
-        # (L, B, S, kv, hd)
-        _, _, s, kv, hd = leaf_shape
+        # (L, B, kv, S, hd)
+        _, _, kv, s, hd = leaf_shape
         used = {a for a in (batch_ax if isinstance(batch_ax, tuple)
                             else (batch_ax,)) if a}
         seq_ax = None
@@ -120,7 +120,7 @@ def cache_shardings(cfg: ArchConfig, mesh, shape: ShapeConfig):
                 seq_ax = cand
         elif "model" not in used and s % mesh.shape["model"] == 0:
             seq_ax = "model"
-        return P(None, batch_ax, seq_ax, None, None)
+        return P(None, batch_ax, None, seq_ax, None)
 
     def ssm_spec(leaf_shape):
         # (L, B, nh, p, n)
@@ -222,7 +222,7 @@ def state_bytes_per_device(cfg: ArchConfig, shape: ShapeConfig,
                 if batch_ax is not None:
                     for a in used:
                         denom *= mesh.shape[a]
-                s = leaf.shape[2]
+                s = leaf.shape[3]
                 if batch_ax is None and s % (mesh.shape["data"]
                                              * mesh.shape["model"]) == 0:
                     denom *= mesh.shape["data"] * mesh.shape["model"]

@@ -211,10 +211,32 @@ def param_logical(cfg: ArchConfig) -> PyTree:
 # Forward (train / prefill)
 # ---------------------------------------------------------------------
 
+def _write_rows(c, rows, layer, pos):
+    """``c`` (L, B, Hkv, S, hd) with ``rows`` (B, Hkv, 1, hd) written into
+    layer ``layer`` at position ``pos``: a scalar, or (B,) with slot b's
+    row at ``pos[b]`` (one update per slot, each in place in a carried
+    ``c``)."""
+    if jnp.ndim(pos) == 0:
+        return jax.lax.dynamic_update_slice(c, rows[None],
+                                            (layer, 0, 0, pos, 0))
+    for b in range(rows.shape[0]):
+        c = jax.lax.dynamic_update_slice(c, rows[b][None, None],
+                                         (layer, b, 0, pos[b], 0))
+    return c
+
+
+def _write_layer(c, new, layer):
+    """``c`` (L, ...) with layer ``layer`` replaced by ``new``."""
+    return jax.lax.dynamic_update_slice(
+        c, new[None].astype(c.dtype), (layer,) + (0,) * (c.ndim - 1))
+
+
 def _attn_apply(p, cfg: ArchConfig, x, kind, positions, cache_kv=None,
-                pos: Optional[jnp.ndarray] = None, kv_len=None,
-                kv_scale=None):
-    """kind: per-layer scalar (0 local / 1 global).  Returns (out, (k,v))."""
+                pos: Optional[jnp.ndarray] = None, kv_scale=None, layer=None):
+    """kind: per-layer scalar (0 local / 1 global).  ``cache_kv`` and
+    ``kv_scale`` are the whole stacked (L, ...) cache, of which this is
+    layer ``layer``; they come back with this layer's new entries written
+    in.  Returns (out, kv, kv_scale)."""
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = x @ p["wq"]
@@ -235,26 +257,23 @@ def _attn_apply(p, cfg: ArchConfig, x, kind, positions, cache_kv=None,
         if s == 1:
             # decode: per-slot write positions (ragged continuous batching)
             posv = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
-            upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(
-                c, u, (p, 0, 0)))
             if kv_q:
                 # quantize the new entries to the prefill-time scales
-                sk, sv = kv_scale
+                sk, sv = (a[layer] for a in kv_scale)
                 kq = jnp.clip(jnp.round(k / sk), -127, 127).astype(
                     jnp.int8)
                 vq = jnp.clip(jnp.round(v / sv), -127, 127).astype(
                     jnp.int8)
             else:
                 kq, vq = k.astype(ck.dtype), v.astype(cv.dtype)
-            ck = upd(ck, kq, posv)
-            cv = upd(cv, vq, posv)
+            ck = _write_rows(ck, kq.swapaxes(1, 2), layer, pos)
+            cv = _write_rows(cv, vq.swapaxes(1, 2), layer, pos)
             new_cache = (ck, cv)
             # attend over the cache (padded; mask via kv_len)
+            k_all, v_all = (a[layer].swapaxes(1, 2) for a in (ck, cv))
             if kv_q:
-                k_all = (ck.astype(jnp.float32) * sk).astype(q.dtype)
-                v_all = (cv.astype(jnp.float32) * sv).astype(q.dtype)
-            else:
-                k_all, v_all = ck, cv
+                k_all = (k_all.astype(jnp.float32) * sk).astype(q.dtype)
+                v_all = (v_all.astype(jnp.float32) * sv).astype(q.dtype)
             q_offset = posv
             kv_len_eff = posv + 1
         else:
@@ -268,11 +287,13 @@ def _attn_apply(p, cfg: ArchConfig, x, kind, positions, cache_kv=None,
                     jnp.int8)
                 vq = jnp.clip(jnp.round(v / sv), -127, 127).astype(
                     jnp.int8)
-                new_scale = (sk, sv)
+                new_scale = tuple(_write_layer(a, n, layer)
+                                  for a, n in zip(kv_scale, (sk, sv)))
             else:
                 kq, vq = k.astype(ck.dtype), v.astype(cv.dtype)
-            ck = jax.lax.dynamic_update_slice(ck, kq, (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, vq, (0, pos, 0, 0))
+            # the prompt's rows, from position ``pos`` of this layer
+            ck = _write_rows(ck, kq.swapaxes(1, 2), layer, pos)
+            cv = _write_rows(cv, vq.swapaxes(1, 2), layer, pos)
             new_cache = (ck, cv)
             # prefill: the fresh k/v ARE the valid cache prefix
             k_all, v_all = k, v
@@ -296,8 +317,10 @@ def _attn_apply(p, cfg: ArchConfig, x, kind, positions, cache_kv=None,
 
 
 def _block_apply(cfg: ArchConfig, params, kind, x, positions,
-                 cache=None, pos=None):
-    """One decoder layer.  cache: dict of per-layer state or None."""
+                 cache=None, pos=None, layer=None):
+    """One decoder layer.  cache: the whole stacked cache dict, of which
+    this is layer ``layer``, or None; it comes back with this layer's new
+    state written in."""
     if QUANT_BITS:
         params = Q.dequant_tree(params, QUANT_BITS,
                                 dtype=params["ln1"].dtype)
@@ -310,7 +333,8 @@ def _block_apply(cfg: ArchConfig, params, kind, x, positions,
             params["attn"], cfg, h, kind, positions,
             cache_kv=None if cache is None else cache.get("kv"),
             pos=pos,
-            kv_scale=None if cache is None else cache.get("kv_scale"))
+            kv_scale=None if cache is None else cache.get("kv_scale"),
+            layer=layer)
         new_cache["kv"] = kv
         if kv_scale is not None:
             new_cache["kv_scale"] = kv_scale
@@ -318,10 +342,11 @@ def _block_apply(cfg: ArchConfig, params, kind, x, positions,
     if cfg.ssm is not None:
         y, st, cst = SSM.ssm_block(
             params["ssm"], h, cfg.ssm,
-            state=None if cache is None else cache.get("ssm"),
-            conv_state=None if cache is None else cache.get("conv"))
-        new_cache["ssm"] = st
-        new_cache["conv"] = cst
+            state=None if cache is None else cache["ssm"][layer],
+            conv_state=None if cache is None else cache["conv"][layer])
+        if cache is not None:
+            new_cache["ssm"] = _write_layer(cache["ssm"], st, layer)
+            new_cache["conv"] = _write_layer(cache["conv"], cst, layer)
         if cfg.family == "hybrid":
             # Hymba: parallel attn + SSM heads, normalized mean fusion.
             mix = 0.5 * (_rmsn(mix) + _rmsn(y))
@@ -439,10 +464,13 @@ def loss_fn(cfg: ArchConfig, params, batch, remat: bool = True):
 
 def init_cache(cfg: ArchConfig, batch: int, seq: int,
                dtype=jnp.bfloat16) -> PyTree:
+    """The serving cache.  K and V are (L, B, Hkv, S, hd): heads before
+    positions, the order decode attention reads a layer in, so that the
+    layer is read where it lies and not copied out in another order."""
     cache = {}
     nl = cfg.n_layers
     if not cfg.attention_free:
-        kv_shape = (nl, batch, seq, cfg.n_kv_heads, cfg.d_head)
+        kv_shape = (nl, batch, cfg.n_kv_heads, seq, cfg.d_head)
         kv_dtype = jnp.int8 if KV_QUANT else dtype
         cache["kv"] = (jnp.zeros(kv_shape, kv_dtype),
                        jnp.zeros(kv_shape, kv_dtype))
@@ -461,40 +489,23 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int,
     return cache
 
 
-def _cache_layer(cache, i=None):
-    """Slice / restructure helpers handled by scan's xs mechanism."""
-    return cache
-
-
 def _serve_scan(cfg: ArchConfig, params, x, positions, cache, pos):
+    """The layers over ``x`` with the cache: the whole cache rides in the
+    scan's carry, and each layer writes only its new entries into it, so a
+    donated cache is updated in place and never copied whole."""
     kinds = layer_kinds(cfg)
 
     def body(carry, scanned):
-        xc = carry
-        blk, kind, layer_cache = scanned
-        lc = {}
-        if "kv" in layer_cache:
-            lc["kv"] = layer_cache["kv"]
-            if "kv_scale" in layer_cache:
-                lc["kv_scale"] = layer_cache["kv_scale"]
-        if "ssm" in layer_cache:
-            lc["ssm"] = layer_cache["ssm"]
-            lc["conv"] = layer_cache["conv"]
-        xc, _, new_lc = _block_apply(cfg, blk, kind, xc, positions,
-                                     cache=lc, pos=pos)
-        out = {}
-        if "kv" in new_lc:
-            out["kv"] = tuple(a.astype(layer_cache["kv"][0].dtype)
-                              for a in new_lc["kv"])
-            if "kv_scale" in new_lc:
-                out["kv_scale"] = new_lc["kv_scale"]
-        if "ssm" in new_lc:
-            out["ssm"] = new_lc["ssm"]
-            out["conv"] = new_lc["conv"].astype(layer_cache["conv"].dtype)
-        return xc, out
+        xc, c = carry
+        blk, kind, layer = scanned
+        xc, _, c = _block_apply(cfg, blk, kind, xc, positions,
+                                cache=c, pos=pos, layer=layer)
+        return (xc, c), None
 
-    x, new_cache = _scan(body, x, (params["blocks"], kinds, cache))
-    return x, new_cache
+    (x, cache), _ = _scan(body, (x, cache),
+                          (params["blocks"], kinds,
+                           jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return x, cache
 
 
 def prefill(cfg: ArchConfig, params, batch, cache):
@@ -507,7 +518,8 @@ def prefill(cfg: ArchConfig, params, batch, cache):
 
 
 def decode_step(cfg: ArchConfig, params, cache, token, pos):
-    """One decode step.  token (B, 1) int32 or embeds (B,1,d); pos scalar.
+    """One decode step.  token (B, 1) int32 or embeds (B, 1, d); pos (B,)
+    int32, each slot's write position (a scalar puts every slot there).
 
     This is the PIM-offload target: with batch B it is a batch of GEMVs
     against every projection matrix (see serving/offload.py).
@@ -530,22 +542,25 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos):
     return (x @ _head_matrix(cfg, params))[:, 0], new_cache
 
 
-def _jit_named(fn, name: str):
+def _jit_named(fn, name: str, donate: int):
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=donate)
 
 
 def jit_prefill(cfg: ArchConfig):
     """``prefill`` of ``cfg``, jitted as ``(params, batch, cache)``; its XLA
-    module is named ``jit_prefill`` in a trace."""
-    return _jit_named(lambda p, b, c: prefill(cfg, p, b, c), "prefill")
+    module is named ``jit_prefill`` in a trace.  The cache is donated: the
+    returned cache reuses its buffers, and the one passed in is gone."""
+    return _jit_named(lambda p, b, c: prefill(cfg, p, b, c), "prefill", 2)
 
 
 def jit_decode_step(cfg: ArchConfig):
     """``decode_step`` of ``cfg``, jitted as ``(params, cache, token, pos)``;
-    its XLA module is named ``jit_decode_step`` in a trace."""
+    its XLA module is named ``jit_decode_step`` in a trace.  The cache is
+    donated: the returned cache reuses its buffers, and the one passed in
+    is gone."""
     return _jit_named(lambda p, c, t, pos: decode_step(cfg, p, c, t, pos),
-                      "decode_step")
+                      "decode_step", 1)
 
 
 # ---------------------------------------------------------------------

@@ -113,6 +113,24 @@ def test_gemma3_4b_decode_step_fits_one_chip(one_chip, gemma_params):
     assert _hbm_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_gemma3_4b_decode_step_updates_cache_in_place(one_chip,
+                                                      gemma_params):
+    """The served decode step aliases its f32 cache and holds no copy of
+    it: the chip keeps a layer's K/V in the order attention reads them, so
+    no relayout of the whole cache goes in or out of the layer scan."""
+    cfg, params = gemma_params
+    slots = 4
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, slots, 2048, jnp.float32)))
+    token = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    ma = M.jit_decode_step(cfg).lower(
+        params, cache, token, pos).compile().memory_analysis()
+    nbytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert ma.alias_size_in_bytes >= nbytes
+    assert ma.temp_size_in_bytes < nbytes
+
+
 def test_gemma3_4b_prefill_fits_one_chip(one_chip, gemma_params):
     cfg, params = gemma_params
     cache = _on(one_chip, jax.eval_shape(
