@@ -1,8 +1,11 @@
 """Operations and bytes the algorithm needs, from the configuration's shapes.
 
-Kept with the benchmark so that every PR counts the same way.  Bytes are
-counted at the configuration's parameter type (bfloat16, 2 bytes), also
-for the KV cache, whatever type the program keeps it in.
+Kept with the benchmark so that every PR counts the same way.  Each
+architecture's module (``bench/arch/<arch>.py``) counts its own layers by
+these rules; the readers in ``bench/metrics/`` call the functions here,
+which combine them.  Bytes are counted at the configuration's parameter
+type (bfloat16, 2 bytes), also for the KV cache, whatever type the
+program keeps it in.
 
 * Weight bytes of one decode step: every matrix that a token multiplies
   against, read once for the whole batch (attention, MLP or the experts
@@ -10,8 +13,8 @@ for the KV cache, whatever type the program keeps it in.
   the batch gathers.  For a mixture of experts the experts needed are
   the expected number that ``B`` tokens reach with ``k`` of ``E``
   experts each, under uniform routing: ``E * (1 - (1 - k / E) ** B)``.
-* KV bytes of one decode step: keys and values at every live position of
-  every active slot.
+* State bytes of one decode step: keys and values at every live position
+  of every active slot, and any fixed-size recurrent state of each.
 * Model FLOPs of a token: 2 per multiply-add of every weight matrix it
   passes (only the top-k experts, the head only where logits are
   computed), plus causal attention at the live context length.
@@ -21,79 +24,30 @@ from __future__ import annotations
 import hashlib
 import re
 
-from model_config import dims
+import registry
 from reference.pim_ref import ACT, MAC, PRE, RD
 
 BYTES = 2    # bfloat16
 
 
-def _attn_params(d: dict) -> int:
-    return d["d"] * d["hd"] * (2 * d["hq"] + 2 * d["hkv"])
-
-
-def _ffn_params_per_token(d: dict) -> int:
-    if d["E"]:
-        return d["d"] * d["E"] + d["k"] * 3 * d["d"] * d["ff"]
-    return 3 * d["d"] * d["ff"]
-
-
-def experts_reached(d: dict, batch: int) -> float:
-    e, k = d["E"], d["k"]
-    return e * (1.0 - (1.0 - k / e) ** batch)
-
-
-def decode_weight_bytes(c: dict, batch: int) -> float:
-    d = dims(c)
-    per_layer = _attn_params(d) + 2 * d["d"]
-    if d["E"]:
-        per_layer += d["d"] * d["E"]
-        per_layer += experts_reached(d, batch) * 3 * d["d"] * d["ff"]
-    else:
-        per_layer += 3 * d["d"] * d["ff"]
-    head = d["d"] * d["V"]
-    gathered = batch * d["d"]
-    return BYTES * (d["L"] * per_layer + head + d["d"] + gathered)
-
-
-def kv_bytes(c: dict, context: int) -> float:
-    """Keys and values of one slot at ``context`` live positions."""
-    d = dims(c)
-    return BYTES * 2 * d["L"] * d["hkv"] * d["hd"] * context
-
-
 def decode_step_bytes(c: dict, contexts: list[int]) -> float:
     """Least bytes of one decode step whose active slots attend over
-    ``contexts`` positions each."""
-    return (decode_weight_bytes(c, len(contexts))
-            + sum(kv_bytes(c, n) for n in contexts))
+    ``contexts`` positions each: the weights, and each slot's state."""
+    a = registry.arch(c)
+    return (a.decode_weight_bytes(c, len(contexts))
+            + sum(a.state_bytes(c, n) for n in contexts))
 
 
 def token_flops(c: dict, context: int, logits: bool) -> float:
     """Model FLOPs of one token at position ``context - 1``."""
-    d = dims(c)
-    lin = d["L"] * (_attn_params(d) + _ffn_params_per_token(d))
-    if logits:
-        lin += d["d"] * d["V"]
-    attn = d["L"] * 2 * 2 * context * d["hq"] * d["hd"]
-    return 2.0 * lin + attn
+    return registry.arch(c).token_flops(c, context, logits)
 
 
 def prefill_flops(c: dict, prompt: int) -> float:
     """A prompt of ``prompt`` tokens, logits for the last one only."""
-    return (sum(token_flops(c, p + 1, False) for p in range(prompt))
-            + 2.0 * dims(c)["d"] * dims(c)["V"])
-
-
-def param_bytes(c: dict) -> float:
-    """Bytes of the whole parameter tree at the parameter type."""
-    d = dims(c)
-    per_layer = _attn_params(d) + 2 * d["d"]
-    if d["E"]:
-        per_layer += d["d"] * d["E"] + d["E"] * 3 * d["d"] * d["ff"]
-    else:
-        per_layer += 3 * d["d"] * d["ff"]
-    top = d["V"] * d["d"] * (1 if d["tied"] else 2) + d["d"]
-    return BYTES * (d["L"] * per_layer + top)
+    a = registry.arch(c)
+    head = a.token_flops(c, 1, True) - a.token_flops(c, 1, False)
+    return sum(a.token_flops(c, p + 1, False) for p in range(prompt)) + head
 
 
 # -- the simulator sweep ----------------------------------------------------

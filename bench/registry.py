@@ -7,10 +7,13 @@ per-layer metric lives in a file of its own:
 * ``bench/traffic/<traffic>.json``: the parameters of one traffic mix,
   read by the one general generator in ``loadgen.py``;
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric, a
-  function ``read(run) -> float | None``.
+  function ``read(run) -> float | None``;
+* ``bench/arch/<arch>.py``: what the benchmark knows of one architecture
+  (named by the configuration file's ``"arch"``): the program's config,
+  the weight schema, the plain reference and the counts.
 
-A new cell, mix or metric therefore needs only new files and new entries
-in ``BENCHMARK.json``; nothing here names a cell.
+A new cell, mix, metric or architecture therefore needs only new files
+and new entries in ``BENCHMARK.json``; nothing here names a cell.
 """
 from __future__ import annotations
 
@@ -59,6 +62,27 @@ def metric_reader(name: str, bench_dir: str = BENCH):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+_ARCHS: dict = {}
+
+
+def arch(c: dict, bench_dir: str = BENCH):
+    """The module ``bench/arch/<c["arch"]>.py``, loaded once a process."""
+    name = c.get("arch")
+    path = os.path.join(bench_dir, "arch", f"{name}.py")
+    if path not in _ARCHS:
+        have = sorted(f.removesuffix(".py") for f in os.listdir(
+            os.path.join(bench_dir, "arch")) if f.endswith(".py"))
+        if name not in have:
+            raise SystemExit(f"config {c.get('name')!r} names arch "
+                             f"{name!r}; bench/arch/ has {have}")
+        spec = importlib.util.spec_from_file_location(
+            "bench_arch_" + name.replace("-", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _ARCHS[path] = mod
+    return _ARCHS[path]
 
 
 def _reports(metric: dict, cell: str) -> bool:

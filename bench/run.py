@@ -89,13 +89,16 @@ class Tracer:
     ``seconds`` later from a timer thread, the sample inside its own
     ``bench.window`` span.  That is for programs whose device events come
     too fast for the profiler to keep a whole window (the resolver's scan
-    records every iteration)."""
+    records every iteration).  ``begin()`` and ``end()`` do the same from
+    the caller's own thread, at points it chooses (a serving loop's step
+    boundaries); the window's end stops a sample still running."""
 
     def __init__(self, on: bool):
         self.on = on
         self.dir = tempfile.mkdtemp(prefix="bench-trace-") if on else None
         self.sampled = False
         self._thread = None
+        self._span = None       # the sample's bench.window, while it runs
 
     def _start(self):
         import jax
@@ -120,12 +123,31 @@ class Tracer:
         self._thread = threading.Thread(target=stop, daemon=True)
         self._thread.start()
 
+    def begin(self):
+        if not self.on or self._thread is not None or self._span:
+            return
+        import jax
+
+        self._start()
+        self._span = jax.profiler.TraceAnnotation("bench.window")
+        self._span.__enter__()
+
+    def end(self):
+        if not self._span:
+            return
+        import jax
+
+        self._span.__exit__(None, None, None)
+        self._span = False
+        jax.profiler.stop_trace()
+
     def __enter__(self):
         if self.on and not self.sampled:
             self._start()
         return self
 
     def __exit__(self, *exc):
+        self.end()
         if self._thread is not None:
             self._thread.join()
         elif self.on and not self.sampled:

@@ -34,8 +34,8 @@ import repro.core  # noqa: F401 - before repro.pimkernel (import cycle)
 from repro.pimkernel.executor import GemvRequest, PimExecutor
 
 import loadgen
+import registry
 from counts import gemv_weight_commands, sweep_counts
-from model_config import gemv_shapes
 from reference import pim_ref
 
 GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -67,7 +67,7 @@ def requests(c: dict, mix: dict, point: dict):
                           num_ranks=fam["num_ranks"],
                           fence_ns=fam["fence_ns"],
                           refresh_enabled=fam["refresh_enabled"])
-        for h, w in gemv_shapes(c):
+        for h, w in registry.arch(c).gemv_shapes(c):
             for var in mix["variants"]:
                 if var["kind"] == "pim":
                     reqs.append(GemvRequest.pim(

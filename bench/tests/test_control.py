@@ -8,15 +8,21 @@ import pytest
 import tiny
 
 
+@pytest.mark.parametrize("mix,cfg", [("open_loop", "TINY"),
+                                     ("offline", "TINY"),
+                                     ("offline", "TINY_P99")])
 @pytest.mark.parametrize("seed", [11, 2 ** 33 + 5, 977])
-def test_fp8_control_in_the_programs_place_is_not_correct(seed):
-    prog = tiny.serve(seed=seed)
-    ctl = tiny.serve(seed=seed, control=True)
+def test_fp8_control_in_the_programs_place_is_not_correct(seed, mix, cfg):
+    mix = tiny.OPEN if mix == "open_loop" else tiny.OFFLINE
+    cfg = getattr(tiny, cfg)
+    (number, limit), = cfg["check"].items()
+    prog = tiny.serve(cfg=cfg, seed=seed, mix=mix)
+    ctl = tiny.serve(cfg=cfg, seed=seed, mix=mix, control=True)
     assert prog["correct"], prog["checks"]
     assert not ctl["correct"], ctl["checks"]
-    gap = ctl["checks"]["max_logit_gap"]["value"]
-    assert gap > tiny.TINY["check"]["max_logit_gap"]
-    assert gap >= 3 * prog["checks"]["max_logit_gap"]["value"]
+    gap = ctl["checks"][number]["value"]
+    assert gap > limit
+    assert gap >= 3 * prog["checks"][number]["value"]
 
 
 @pytest.mark.parametrize("seed", [11, 2 ** 33 + 5, 977])

@@ -15,21 +15,22 @@ def _cfg(name):
 
 def test_granite8b_weights_by_hand():
     c = _cfg("granite-8b")
+    a = registry.arch(c)
     # per layer: q,o 4096x4096, k,v 4096x1024, SwiGLU 3 x 4096x14336,
     # two norms; 18 layers; embedding and head 49152 x 4096 each; ln_f
     per_layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336 \
         + 2 * 4096
     params = 18 * per_layer + 2 * 49152 * 4096 + 4096
     assert params == 4_328_673_280
-    assert counts.param_bytes(c) == 2 * params          # 8.66 GB
+    assert a.param_bytes(c) == 2 * params               # 8.66 GB
     # a decode step reads everything but the embedding table, whose
     # batch rows it gathers instead
     step = 2 * (18 * per_layer + 49152 * 4096 + 4096 + 8 * 4096)
-    assert counts.decode_weight_bytes(c, 8) == step
+    assert a.decode_weight_bytes(c, 8) == step
     # KV at bf16: 18 layers x 8 kv heads x 128 x (k, v) x 2 B a position
-    assert counts.kv_bytes(c, 100) == 100 * 18 * 8 * 128 * 2 * 2
+    assert a.state_bytes(c, 100) == 100 * 18 * 8 * 128 * 2 * 2
     assert counts.decode_step_bytes(c, [100, 200]) == \
-        counts.decode_weight_bytes(c, 2) + counts.kv_bytes(c, 300)
+        a.decode_weight_bytes(c, 2) + a.state_bytes(c, 300)
 
 
 def test_granite8b_token_flops_by_hand():
@@ -46,15 +47,16 @@ def test_granite8b_token_flops_by_hand():
 
 def test_moe_counts_only_routed_experts():
     c = _cfg("granite-moe-3b-a800m")
+    a = registry.arch(c)
     e, k, d, ff = 40, 8, 1536, 512
-    one = counts.decode_weight_bytes(c, 1)
-    eight = counts.decode_weight_bytes(c, 8)
+    one = a.decode_weight_bytes(c, 1)
+    eight = a.decode_weight_bytes(c, 8)
     per_expert = 2 * 32 * 3 * d * ff
     assert eight - one == pytest.approx(
         per_expert * e * ((1 - k / e) - (1 - k / e) ** 8)
         + 2 * 7 * d)
     # the tied embedding is counted once, as the head
-    assert counts.param_bytes(c) == 2 * (
+    assert a.param_bytes(c) == 2 * (
         32 * (d * 64 * (2 * 24 + 2 * 8) + 2 * d + d * e + e * 3 * d * ff)
         + 49408 * d + d)
 

@@ -35,10 +35,13 @@ def _broken_decode(monkeypatch, fault):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "token_altered"])
-@pytest.mark.parametrize("mix", ["open_loop", "offline"])
-def test_broken_serve_run_is_not_correct(monkeypatch, fault, mix):
+@pytest.mark.parametrize("mix,cfg", [("open_loop", "TINY"),
+                                     ("offline", "TINY"),
+                                     ("offline", "TINY_P99")])
+def test_broken_serve_run_is_not_correct(monkeypatch, fault, mix, cfg):
     _broken_decode(monkeypatch, fault)
-    res = tiny.serve(mix=tiny.OPEN if mix == "open_loop" else tiny.OFFLINE)
+    res = tiny.serve(cfg=getattr(tiny, cfg),
+                     mix=tiny.OPEN if mix == "open_loop" else tiny.OFFLINE)
     assert not res["correct"], res["checks"]
 
 

@@ -13,7 +13,7 @@ ROOT = os.path.dirname(BENCH)
 def test_every_name_in_benchmark_json_has_its_file():
     b = registry.load_benchmark()
     for w in b["workloads"]:
-        registry.config(b, w["config"])
+        registry.arch(registry.config(b, w["config"]))
         mix = registry.traffic(w["traffic"])
         assert mix["kind"] in ("open_loop", "offline", "design_sweep")
         assert registry.end_to_end(b, w["name"])

@@ -131,3 +131,28 @@ def test_decode_trace_readers():
                      host[:1], ops), window_ns=(0, 100 * MS))
     assert metric_reader("decode_device_ms.serve")(old) is None
     assert metric_reader("decode_host_ms.serve")(old) is None
+
+
+def test_decode_roofline_counts_only_steps_whose_device_events_were_kept():
+    import tiny
+    from counts import decode_step_bytes
+
+    def step(i, start, dur):
+        stats = types.SimpleNamespace(stats=[("i", i)])
+        return ("bench.step", start, dur, stats)
+
+    host = [_ev("bench.window", 0, 100 * MS), step(0, 10 * MS, 10 * MS),
+            step(1, 30 * MS, 10 * MS), step(2, 50 * MS, 10 * MS)]
+    ops = [("fusion.1", 11 * MS, 8 * MS), ("fusion.2", 51 * MS, 4 * MS)]
+    modules = [("jit_decode_step(17)", 11 * MS, 8 * MS),
+               ("jit_decode_step(17)", 51 * MS, 4 * MS)]
+    steps = [dict(i=i, admit=False, decode_ctx=[100 * (i + 1), 50])
+             for i in range(3)]             # step 1's events were dropped
+    run = types.SimpleNamespace(trace=_trace(modules, host, ops),
+                                window_ns=(0, 100 * MS), steps=steps,
+                                config=tiny.TINY,
+                                peaks=dict(hbm_bytes_per_s=1e12))
+    least = (decode_step_bytes(tiny.TINY, [100, 50])
+             + decode_step_bytes(tiny.TINY, [300, 50])) / 1e12
+    assert metric_reader("decode_roofline.serve")(run) == \
+        pytest.approx(100 * least / 0.012)

@@ -4,7 +4,7 @@ import copy
 
 import jax
 
-TINY = dict(name="tiny", source="test", hidden_size=64,
+TINY = dict(name="tiny", arch="decoder", source="test", hidden_size=64,
             intermediate_size=128, num_attention_heads=4,
             num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
             vocab_size=500, hidden_act="silu", rms_norm_eps=1e-5,
@@ -12,6 +12,10 @@ TINY = dict(name="tiny", source="test", hidden_size=64,
             serve=dict(dtype="bfloat16", slots=4, max_seq=256,
                        planner_layers=2),
             check=dict(max_logit_gap=0.02))
+
+# The same model, checked as the offline MoE cell is: on the 99th
+# percentile of the served tokens' gaps.
+TINY_P99 = dict(TINY, name="tiny-p99", check=dict(p99_logit_gap=0.02))
 
 TINY_MOE = dict(copy.deepcopy(TINY), name="tiny-moe", num_local_experts=4,
                 num_experts_per_tok=2, tie_word_embeddings=True)
@@ -24,7 +28,7 @@ OPEN = dict(kind="open_loop", rate_per_s=20.0, block=8,
 OFFLINE = dict(OPEN, kind="offline", backlog=6, block=8, blocks=8)
 
 # A model whose decode GEMV shapes are small enough for a CPU sweep.
-SWEEP_MODEL = dict(name="tiny-sweep", hidden_size=256,
+SWEEP_MODEL = dict(name="tiny-sweep", arch="decoder", hidden_size=256,
                    intermediate_size=128, num_attention_heads=4,
                    num_key_value_heads=2, head_dim=64, num_hidden_layers=2,
                    vocab_size=500, num_local_experts=8,

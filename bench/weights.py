@@ -9,45 +9,17 @@ random bits never exceed one layer's.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 
-from model_config import dims
+import registry
 
 NORM_STD = 0.1
 
 
-def layer_schema(c: dict) -> dict:
-    """{path: (shape, std)} of one decoder layer; std None = a norm
-    weight, stored as the program's ``gamma`` (scale ``1 + gamma``)."""
-    d = dims(c)
-    dm, hq, hkv, hd, ff = d["d"], d["hq"], d["hkv"], d["hd"], d["ff"]
-    s = {"ln1": ((dm,), None), "ln2": ((dm,), None),
-         "attn/wq": ((dm, hq * hd), 1 / math.sqrt(dm)),
-         "attn/wk": ((dm, hkv * hd), 1 / math.sqrt(dm)),
-         "attn/wv": ((dm, hkv * hd), 1 / math.sqrt(dm)),
-         "attn/wo": ((hq * hd, dm), 1 / math.sqrt(hq * hd))}
-    if d["E"]:
-        e = d["E"]
-        s.update({"moe/router": ((dm, e), 1 / math.sqrt(dm)),
-                  "moe/wi": ((e, dm, ff), 1 / math.sqrt(dm)),
-                  "moe/wg": ((e, dm, ff), 1 / math.sqrt(dm)),
-                  "moe/wo": ((e, ff, dm), 1 / math.sqrt(ff))})
-    else:
-        s.update({"mlp/wi": ((dm, ff), 1 / math.sqrt(dm)),
-                  "mlp/wg": ((dm, ff), 1 / math.sqrt(dm)),
-                  "mlp/wo": ((ff, dm), 1 / math.sqrt(ff))})
-    return s
-
-
-def top_schema(c: dict) -> dict:
-    d = dims(c)
-    s = {"embed": ((d["V"], d["d"]), 0.02), "ln_f": ((d["d"],), None)}
-    if not d["tied"]:
-        s["lm_head"] = ((d["d"], d["V"]), 0.02)
-    return s
+def padded_vocab(vocab: int) -> int:
+    """The program pads its embedding and head to a multiple of 256."""
+    return -(-vocab // 256) * 256
 
 
 def _nest(flat: dict) -> dict:
@@ -68,23 +40,28 @@ def _draw(key, shape, std, dtype):
 
 
 def make_params(c: dict, seed: int, dtype=jnp.bfloat16):
-    """The whole parameter tree for config file ``c`` from ``seed``."""
-    L = dims(c)["L"]
-    lay, top = layer_schema(c), top_schema(c)
+    """The whole parameter tree for config file ``c`` from ``seed``, drawn
+    from its architecture's ``schema``: ``{"top": {path: (shape, std)},
+    "stacks": {name: (count, {path: (shape, std)})}}``; std None is a
+    norm weight, stored as the program's ``gamma`` (scale ``1 + gamma``).
+    Each stack gets a key of its own, and each of its layers a key split
+    from that one."""
+    s = registry.arch(c).schema(c)
+    top, stacks = s["top"], s["stacks"]
 
     @jax.jit
     def build(key):
-        k_top, k_lay = jax.random.split(key)
+        k_top, *k_stacks = jax.random.split(key, 1 + len(stacks))
         flat = {p: _draw(jax.random.fold_in(k_top, i), sh, sd, dtype)
                 for i, (p, (sh, sd)) in enumerate(top.items())}
-
-        def one_layer(k):
-            return {p: _draw(jax.random.fold_in(k, i), sh, sd, dtype)
-                    for i, (p, (sh, sd)) in enumerate(lay.items())}
-
-        blocks = jax.lax.map(one_layer, jax.random.split(k_lay, L))
         out = _nest(flat)
-        out["blocks"] = _nest(blocks)
+        for k, (name, (n, lay)) in zip(k_stacks, stacks.items()):
+            def one_layer(k, lay=lay):
+                return {p: _draw(jax.random.fold_in(k, i), sh, sd, dtype)
+                        for i, (p, (sh, sd)) in enumerate(lay.items())}
+
+            out[name] = _nest(jax.lax.map(one_layer,
+                                          jax.random.split(k, n)))
         return out
 
     return build(jax.random.PRNGKey(seed))
