@@ -15,9 +15,16 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoeConfig:
-    n_experts: int
+    n_experts: int                  # the router's width (published count)
     top_k: int
     capacity_factor: float = 1.25
+    # Expert parallelism: this chip holds ``n_held`` experts, ids
+    # ``first_held`` .. ``first_held + n_held - 1``, and computes only
+    # their part of the layer (None = every expert).
+    n_held: Optional[int] = None
+    first_held: int = 0
+    # A shared expert (SwiGLU of this width) that every token passes.
+    shared_d_ff: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,7 +39,10 @@ class SsmConfig:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                     # dense | moe | ssm | hybrid
+    # dense | moe | ssm | hybrid (Hymba: attention and SSM heads in
+    # parallel in every layer) | interleaved (``layer_types``: each layer
+    # either a Mamba-2 mixer or attention, each with the MoE)
+    family: str
     n_layers: int
     d_model: int
     n_heads: int                    # 0 for attention-free
@@ -51,6 +61,15 @@ class ArchConfig:
     moe: Optional[MoeConfig] = None
     ssm: Optional[SsmConfig] = None
     input_mode: str = "tokens"      # tokens | embeddings (modality stub)
+    # interleaved: "mamba" / "attention" per layer, in order
+    layer_types: tuple = ()
+    position_embedding: str = "rope"    # rope | nope (no rotary)
+    # Granite's multipliers; 1 (attention: None = head_dim ** -0.5)
+    # adds no operation to the program
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     prefix_patches: int = 0         # VLM: patch embeddings before tokens
     # annotations
     source: str = ""
@@ -64,8 +83,12 @@ class ArchConfig:
     @property
     def sub_quadratic(self) -> bool:
         """Eligible for long_500k (SSM / hybrid / sliding-window-local)."""
-        return self.family in ("ssm", "hybrid") or \
+        return self.family in ("ssm", "hybrid", "interleaved") or \
             self.sliding_window is not None
+
+    def n_layers_of(self, kind: str) -> int:
+        """Layers of ``kind`` ("mamba" / "attention") in ``layer_types``."""
+        return sum(t == kind for t in self.layer_types)
 
     @property
     def vocab_padded(self) -> int:

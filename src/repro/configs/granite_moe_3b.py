@@ -1,4 +1,10 @@
-"""Granite-MoE-3B-a800m [hf:ibm-granite/granite-3.0-1b-a400m-base; hf]."""
+"""Granite-MoE-3B-a800m [hf:ibm-granite/granite-3.0-3b-a800m-base; hf].
+
+The widths are the published ones.  Its Granite multipliers (embedding
+12, attention 1/64, residual 0.22, logits 6, as the benchmark's config
+file records them) are expressible as ``ArchConfig`` fields; this
+dry-run/smoke config leaves them at 1, as it always ran.
+"""
 from .base import ArchConfig, MoeConfig
 
 CONFIG = ArchConfig(
@@ -6,7 +12,7 @@ CONFIG = ArchConfig(
     n_layers=32, d_model=1536, n_heads=24, n_kv_heads=8, d_head=64,
     d_ff=512, vocab=49155, mlp="swiglu",
     moe=MoeConfig(n_experts=40, top_k=8),
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base; hf",
+    source="hf:ibm-granite/granite-3.0-3b-a800m-base; hf",
     notes="fine-grained 40-expert top-8 MoE; per-expert d_ff=512 is the "
           "paper's reshape-optimization regime (W<2048)",
 )

@@ -61,13 +61,14 @@ def _mask(qi, ki, window):
     return m
 
 
-def dense_attention(q, k, v, *, window=None, q_offset=0, kv_len=None):
+def dense_attention(q, k, v, *, window=None, q_offset=0, kv_len=None,
+                    scale=None):
     """Quadratic-path GQA attention (short sequences / decode).
 
     q: (B, Sq, Hq, hd); k, v: (B, Sk, Hkv, hd).  ``q_offset`` is the
     absolute position of q[0] — scalar, or (B,) for ragged decode slots;
     ``kv_len`` (scalar or (B,)) masks the valid cache prefix when Sk is a
-    padded cache.
+    padded cache.  ``scale`` multiplies the scores (None: ``hd ** -0.5``).
     """
     b, sq, hq, hd = q.shape
     sk, hkv = k.shape[1], k.shape[2]
@@ -75,7 +76,7 @@ def dense_attention(q, k, v, *, window=None, q_offset=0, kv_len=None):
     qg = q.reshape(b, sq, hkv, g, hd)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
                         preferred_element_type=jnp.float32)
-    scores *= 1.0 / math.sqrt(hd)
+    scores *= 1.0 / math.sqrt(hd) if scale is None else scale
     qo = jnp.asarray(q_offset)
     qi = (qo[:, None] if qo.ndim == 1 else qo) + jnp.arange(sq)
     qi = jnp.broadcast_to(qi.reshape(-1, sq) if qi.ndim > 1
@@ -96,7 +97,7 @@ def dense_attention(q, k, v, *, window=None, q_offset=0, kv_len=None):
 
 
 def flash_attention(q, k, v, *, window=None, q_offset=0,
-                    block_q: int = 512, block_k: int = 512):
+                    block_q: int = 512, block_k: int = 512, scale=None):
     """Blockwise streaming-softmax attention (prefill / train on long S).
 
     Never materializes more than (B, Hkv, G, block_q, block_k) scores.
@@ -114,7 +115,8 @@ def flash_attention(q, k, v, *, window=None, q_offset=0,
     qb = qp.reshape(b, nq, bq, hkv, g, hd).transpose(1, 0, 3, 4, 2, 5)
     kb = kp.reshape(b, nk, bk, hkv, hd).transpose(1, 0, 3, 2, 4)
     vb = vp.reshape(b, nk, bk, hkv, hd).transpose(1, 0, 3, 2, 4)
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     def kv_scan(qblk, qi, kb_sel, vb_sel, k_idx):
         def kv_step(carry, kv_blk):
@@ -164,14 +166,15 @@ def flash_attention(q, k, v, *, window=None, q_offset=0,
 
 
 def attention(q, k, v, *, window=None, q_offset=0, kv_len=None,
-              flash_threshold: int | None = None):
+              flash_threshold: int | None = None, scale=None):
     if flash_threshold is None:
         flash_threshold = FLASH_THRESHOLD
     if q.shape[1] == 1 or k.shape[1] <= flash_threshold:
         return dense_attention(q, k, v, window=window, q_offset=q_offset,
-                               kv_len=kv_len)
+                               kv_len=kv_len, scale=scale)
     assert kv_len is None, "flash path expects unpadded kv"
-    return flash_attention(q, k, v, window=window, q_offset=q_offset)
+    return flash_attention(q, k, v, window=window, q_offset=q_offset,
+                           scale=scale)
 
 
 # ---------------------------------------------------------------------

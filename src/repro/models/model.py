@@ -1,10 +1,20 @@
 """Model assembly: every assigned architecture as one scanned decoder.
 
-A single parameter schema covers all five families (dense / local-global /
-MoE / SSM / hybrid): per-layer parameters are stacked along a leading L
-axis and the backbone is one ``jax.lax.scan`` over layers (bounded HLO for
-the 80-cell dry-run matrix), with per-layer kind flags (local vs global
-attention) as scanned leaves.
+A single parameter schema covers the uniform families (dense /
+local-global / MoE / SSM / hybrid): per-layer parameters are stacked
+along a leading L axis and the backbone is one ``jax.lax.scan`` over
+layers (bounded HLO for the 80-cell dry-run matrix), with per-layer kind
+flags (local vs global attention) as scanned leaves.
+
+The ``interleaved`` family (GraniteMoeHybrid) has layers of two kinds in
+``cfg.layer_types`` order: Mamba-2 mixers (stack ``mamba_blocks``) and
+attention (stack ``attn_blocks``), each followed by the MoE.  Each run of
+consecutive layers of one kind is a scan over that stack; its cache holds
+K/V for the attention layers only and SSM and conv state for the Mamba
+layers only.
+
+Granite's multipliers (embedding, attention, residual, logits) are
+applied where the config sets them; a value of 1 adds no operation.
 
 Public surface:
   init_params(cfg, key)            -> params pytree (stacked layers)
@@ -139,20 +149,72 @@ def _attn_logical(cfg: ArchConfig):
     return p
 
 
-def _layer_init(key, cfg: ArchConfig, dtype):
+def _moe_init(key, cfg: ArchConfig, dtype):
+    """The MoE of one layer: with neither an expert share nor a shared
+    expert, ``moe_init`` from the layer's key as it always was."""
+    if not (cfg.moe.n_held or cfg.moe.shared_d_ff):
+        return MOE.moe_init(key, cfg.d_model, cfg.d_ff, cfg.moe.n_experts,
+                            cfg.mlp, dtype)
+    k1, k2 = jax.random.split(key)
+    p = MOE.moe_init(k1, cfg.d_model, cfg.d_ff, cfg.moe.n_experts,
+                     cfg.mlp, dtype, held=cfg.moe.n_held)
+    if cfg.moe.shared_d_ff:
+        p.update(MOE.shared_init(k2, cfg.d_model, cfg.moe.shared_d_ff,
+                                 dtype))
+    return p
+
+
+def _moe_logical(cfg: ArchConfig):
+    p = MOE.moe_logical(cfg.mlp)
+    if cfg.moe.shared_d_ff:
+        p.update(MOE.shared_logical())
+    return p
+
+
+def _mixers(cfg: ArchConfig, kind: str | None) -> tuple[bool, bool]:
+    """(attention, SSM) of a layer: an interleaved layer's ``kind``
+    ("mamba" / "attention") is its one mixer; otherwise the config's."""
+    if kind is not None:
+        return kind == "attention", kind == "mamba"
+    return not cfg.attention_free, cfg.ssm is not None
+
+
+def _has_moe(cfg: ArchConfig) -> bool:
+    return cfg.family in ("moe", "interleaved")
+
+
+def _layer_init(key, cfg: ArchConfig, dtype, kind: str | None = None):
     ks = jax.random.split(key, 4)
+    attn, ssm = _mixers(cfg, kind)
     p: dict = {"ln1": jnp.zeros((cfg.d_model,), dtype),
                "ln2": jnp.zeros((cfg.d_model,), dtype)}
-    if not cfg.attention_free:
+    if attn:
         p["attn"] = _attn_init(ks[0], cfg, dtype)
-    if cfg.family == "moe":
-        p["moe"] = MOE.moe_init(ks[1], cfg.d_model, cfg.d_ff,
-                                cfg.moe.n_experts, cfg.mlp, dtype)
+    if _has_moe(cfg):
+        p["moe"] = _moe_init(ks[1], cfg, dtype)
     elif cfg.d_ff > 0:
         p["mlp"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff, cfg.mlp, dtype)
-    if cfg.ssm is not None:
+    if ssm:
         p["ssm"] = SSM.ssm_init(ks[2], cfg.d_model, cfg.ssm, dtype)
     return p
+
+
+STACKS = {"mamba": "mamba_blocks", "attention": "attn_blocks"}
+
+
+def segments(cfg: ArchConfig) -> list[tuple[str, int, int]]:
+    """Runs of consecutive layers of one kind in ``cfg.layer_types``:
+    (kind, first, stop) with indices into that kind's stack."""
+    out: list = []
+    seen = {k: 0 for k in STACKS}
+    for kind in cfg.layer_types:
+        i = seen[kind]
+        seen[kind] += 1
+        if out and out[-1][0] == kind:
+            out[-1] = (kind, out[-1][1], i + 1)
+        else:
+            out.append((kind, i, i + 1))
+    return out
 
 
 def layer_kinds(cfg: ArchConfig) -> jnp.ndarray:
@@ -170,10 +232,19 @@ def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> PyTree:
     params = {
         "embed": jax.random.normal(k_emb, (v, cfg.d_model), dtype) * 0.02,
         "ln_f": jnp.zeros((cfg.d_model,), dtype),
-        "blocks": jax.vmap(
-            lambda k: _layer_init(k, cfg, dtype))(
-                jax.random.split(k_layers, cfg.n_layers)),
     }
+    if cfg.layer_types:
+        for i, (kind, name) in enumerate(STACKS.items()):
+            n = cfg.n_layers_of(kind)
+            if n:
+                params[name] = jax.vmap(
+                    lambda k, kind=kind: _layer_init(k, cfg, dtype, kind))(
+                        jax.random.split(jax.random.fold_in(k_layers, i),
+                                         n))
+    else:
+        params["blocks"] = jax.vmap(
+            lambda k: _layer_init(k, cfg, dtype))(
+                jax.random.split(k_layers, cfg.n_layers))
     if not cfg.tie_embeddings:
         params["lm_head"] = jax.random.normal(
             k_head, (cfg.d_model, v), dtype) * 0.02
@@ -183,28 +254,37 @@ def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> PyTree:
     return params
 
 
-def param_logical(cfg: ArchConfig) -> PyTree:
+def _block_logical(cfg: ArchConfig, kind: str | None = None) -> dict:
+    attn, ssm = _mixers(cfg, kind)
     blk: dict = {"ln1": ("layers", "embed"), "ln2": ("layers", "embed")}
-    if not cfg.attention_free:
+    if attn:
         blk["attn"] = {k: ("layers",) + v
                        for k, v in _attn_logical(cfg).items()}
-    if cfg.family == "moe":
+    if _has_moe(cfg):
         blk["moe"] = {k: ("layers",) + v
-                      for k, v in MOE.moe_logical(cfg.mlp).items()}
+                      for k, v in _moe_logical(cfg).items()}
     elif cfg.d_ff > 0:
         blk["mlp"] = {k: ("layers",) + v
                       for k, v in L.mlp_logical(cfg.mlp).items()}
-    if cfg.ssm is not None:
+    if ssm:
         blk["ssm"] = {k: ("layers",) + v
                       for k, v in SSM.ssm_logical().items()}
-    out = {"embed": ("vocab", "embed"), "ln_f": ("embed",),
-           "blocks": blk}
+    return blk
+
+
+def param_logical(cfg: ArchConfig) -> PyTree:
+    out = {"embed": ("vocab", "embed"), "ln_f": ("embed",)}
+    if cfg.layer_types:
+        for kind, name in STACKS.items():
+            if cfg.n_layers_of(kind):
+                out[name] = _block_logical(cfg, kind)
+    else:
+        out["blocks"] = _block_logical(cfg)
     if not cfg.tie_embeddings:
         out["lm_head"] = ("embed", "vocab")
     if cfg.prefix_patches:
         out["patch_proj"] = ("embed", "embed2")
     return out
-
 
 
 # ---------------------------------------------------------------------
@@ -247,8 +327,9 @@ def _attn_apply(p, cfg: ArchConfig, x, kind, positions, cache_kv=None,
     q = q.reshape(b, s, hq, hd)
     k = k.reshape(b, s, hkv, hd)
     v = v.reshape(b, s, hkv, hd)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = L.rope(q, positions, cfg.rope_theta)
+        k = L.rope(k, positions, cfg.rope_theta)
 
     new_scale = kv_scale
     if cache_kv is not None:
@@ -312,38 +393,43 @@ def _attn_apply(p, cfg: ArchConfig, x, kind, positions, cache_kv=None,
         window = jnp.where(kind == 1, big, cfg.sliding_window)
     out = L.attention(q, k_all.astype(q.dtype), v_all.astype(q.dtype),
                       window=window, q_offset=q_offset,
-                      kv_len=kv_len_eff)
+                      kv_len=kv_len_eff, scale=cfg.attention_multiplier)
     return out.reshape(b, s, hq * hd) @ p["wo"], new_cache, new_scale
 
 
 def _block_apply(cfg: ArchConfig, params, kind, x, positions,
                  cache=None, pos=None, layer=None):
-    """One decoder layer.  cache: the whole stacked cache dict, of which
-    this is layer ``layer``, or None; it comes back with this layer's new
-    state written in."""
+    """One decoder layer, with the mixers whose weights it holds
+    (``"attn"``, ``"ssm"``; an interleaved layer holds one).  cache: the
+    whole stacked cache dict, of which this is layer ``layer`` of the
+    mixer's stack, or None; it comes back (None for None) with this
+    layer's new state written in and every other entry as it was."""
     if QUANT_BITS:
         params = Q.dequant_tree(params, QUANT_BITS,
                                 dtype=params["ln1"].dtype)
     aux = jnp.zeros((), jnp.float32)
-    new_cache = {}
+    new_cache = {} if cache is None else dict(cache)
     h = L.rms_norm(x, params["ln1"], cfg.norm_eps)
     mix = 0.0
-    if not cfg.attention_free:
-        attn_out, kv, kv_scale = _attn_apply(
-            params["attn"], cfg, h, kind, positions,
-            cache_kv=None if cache is None else cache.get("kv"),
-            pos=pos,
-            kv_scale=None if cache is None else cache.get("kv_scale"),
-            layer=layer)
+    if "attn" in params:
+        with jax.named_scope("attn"):
+            attn_out, kv, kv_scale = _attn_apply(
+                params["attn"], cfg, h, kind, positions,
+                cache_kv=None if cache is None else cache.get("kv"),
+                pos=pos,
+                kv_scale=None if cache is None else cache.get("kv_scale"),
+                layer=layer)
         new_cache["kv"] = kv
         if kv_scale is not None:
             new_cache["kv_scale"] = kv_scale
         mix = checkpoint_name(attn_out, "tp_out")
-    if cfg.ssm is not None:
-        y, st, cst = SSM.ssm_block(
-            params["ssm"], h, cfg.ssm,
-            state=None if cache is None else cache["ssm"][layer],
-            conv_state=None if cache is None else cache["conv"][layer])
+    if "ssm" in params:
+        with jax.named_scope("mamba.mix"):
+            y, st, cst = SSM.ssm_block(
+                params["ssm"], h, cfg.ssm,
+                state=None if cache is None else cache["ssm"][layer],
+                conv_state=None if cache is None else cache["conv"][layer],
+                eps=cfg.norm_eps)
         if cache is not None:
             new_cache["ssm"] = _write_layer(cache["ssm"], st, layer)
             new_cache["conv"] = _write_layer(cache["conv"], cst, layer)
@@ -352,18 +438,51 @@ def _block_apply(cfg: ArchConfig, params, kind, x, positions,
             mix = 0.5 * (_rmsn(mix) + _rmsn(y))
         else:
             mix = y
-    x = x + mix
+    x = x + _mul(mix, cfg.residual_multiplier)
     h = L.rms_norm(x, params["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        y, aux = MOE.moe_apply(params["moe"], h, top_k=cfg.moe.top_k,
-                               capacity_factor=cfg.moe.capacity_factor,
-                               mlp_kind=cfg.mlp)
-    elif cfg.d_ff > 0:
+    if "moe" in params:
+        y, aux = _moe_apply(cfg, params["moe"], h)
+    elif "mlp" in params:
         y = checkpoint_name(L.mlp_apply(params["mlp"], h, cfg.mlp),
                             "tp_out")
     else:
         y = jnp.zeros_like(h)
-    return x + y, aux, new_cache
+    return (x + _mul(y, cfg.residual_multiplier), aux,
+            None if cache is None else new_cache)
+
+
+def _mul(x, m):
+    """``x * m``, decided at trace time: 1 adds no operation."""
+    return x if m == 1 else x * m
+
+
+def _moe_apply(cfg: ArchConfig, p, h):
+    return MOE.moe_apply(p, h, top_k=cfg.moe.top_k,
+                         capacity_factor=cfg.moe.capacity_factor,
+                         mlp_kind=cfg.mlp, first=cfg.moe.first_held)
+
+
+def _typed_layers(cfg: ArchConfig, params, x, positions, cache=None,
+                  pos=None, remat: bool = False):
+    """The interleaved family's layers in ``layer_types`` order: a scan
+    over each run of one kind, the layer's weights indexed out of its
+    stack by the scanned layer id, the cache (if any) in the carry."""
+    aux = jnp.zeros((), jnp.float32)
+    for kind, first, stop in segments(cfg):
+        stack = params[STACKS[kind]]
+
+        def body(carry, layer, stack=stack):
+            xc, c, ax = carry
+            blk = jax.tree.map(lambda w: w[layer], stack)
+            xc, a, c = _block_apply(cfg, blk, 1, xc, positions,
+                                    cache=c, pos=pos, layer=layer)
+            return (xc, c, ax + a), None
+
+        if remat:
+            body = _remat_wrap(body)
+        (x, cache, aux), _ = _scan(body, (x, cache, aux),
+                                   jnp.arange(first, stop, dtype=jnp.int32))
+    return x, aux, cache
 
 
 def _rmsn(x):
@@ -384,6 +503,7 @@ def _embed_inputs(cfg: ArchConfig, params, batch):
                 params["ln_f"].dtype)
         else:
             x = jnp.take(emb, batch["tokens"], axis=0)
+        x = _mul(x, cfg.embedding_multiplier)
         if cfg.prefix_patches:
             patches = batch["patches"] @ _deq(params["patch_proj"])
             x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
@@ -408,12 +528,25 @@ def _backbone(cfg: ArchConfig, params, x, positions, remat: bool = True):
     return x, aux
 
 
+def _layers(cfg: ArchConfig, params, x, positions, remat: bool):
+    if cfg.layer_types:
+        x, aux, _ = _typed_layers(cfg, params, x, positions, remat=remat)
+        return x, aux
+    return _backbone(cfg, params, x, positions, remat)
+
+
+def _logits(cfg: ArchConfig, x, head):
+    """``x @ head``, over ``logits_scaling`` where the config sets it."""
+    out = x @ head
+    return out if cfg.logits_scaling == 1 else out / cfg.logits_scaling
+
+
 def forward(cfg: ArchConfig, params, batch, remat: bool = True):
     """Full-sequence forward -> (logits (B, S, vocab), aux_loss)."""
     x, positions = _embed_inputs(cfg, params, batch)
-    x, aux = _backbone(cfg, params, x, positions, remat)
+    x, aux = _layers(cfg, params, x, positions, remat)
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = x @ _head_matrix(cfg, params)
+    logits = _logits(cfg, x, _head_matrix(cfg, params))
     if cfg.prefix_patches:
         logits = logits[:, cfg.prefix_patches:]
     return logits, aux
@@ -429,7 +562,7 @@ def loss_fn(cfg: ArchConfig, params, batch, remat: bool = True):
     if CE_CHUNKS > 1:
         # chunked CE: never materialize the full (B, S, vocab) logits.
         x, positions = _embed_inputs(cfg, params, batch)
-        x, aux = _backbone(cfg, params, x, positions, remat)
+        x, aux = _layers(cfg, params, x, positions, remat)
         x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
         if cfg.prefix_patches:
             x = x[:, cfg.prefix_patches:]
@@ -444,7 +577,7 @@ def loss_fn(cfg: ArchConfig, params, batch, remat: bool = True):
             mc = mask[:, i * csz:(i + 1) * csz]
             if xc.shape[1] == 0:
                 continue
-            logits_c = (xc @ head).astype(jnp.float32)
+            logits_c = _logits(cfg, xc, head).astype(jnp.float32)
             logp = jax.nn.log_softmax(logits_c, axis=-1)
             nll = -jnp.take_along_axis(logp, lc[..., None],
                                        axis=-1)[..., 0]
@@ -466,26 +599,33 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int,
                dtype=jnp.bfloat16) -> PyTree:
     """The serving cache.  K and V are (L, B, Hkv, S, hd): heads before
     positions, the order decode attention reads a layer in, so that the
-    layer is read where it lies and not copied out in another order."""
+    layer is read where it lies and not copied out in another order.
+    The interleaved family holds K/V for its attention layers only, and
+    SSM and conv state for its Mamba layers only."""
+    if cfg.layer_types:
+        n_kv = cfg.n_layers_of("attention")
+        n_ssm = cfg.n_layers_of("mamba")
+    else:
+        n_kv = 0 if cfg.attention_free else cfg.n_layers
+        n_ssm = cfg.n_layers if cfg.ssm is not None else 0
     cache = {}
-    nl = cfg.n_layers
-    if not cfg.attention_free:
-        kv_shape = (nl, batch, cfg.n_kv_heads, seq, cfg.d_head)
+    if n_kv:
+        kv_shape = (n_kv, batch, cfg.n_kv_heads, seq, cfg.d_head)
         kv_dtype = jnp.int8 if KV_QUANT else dtype
         cache["kv"] = (jnp.zeros(kv_shape, kv_dtype),
                        jnp.zeros(kv_shape, kv_dtype))
         if KV_QUANT:
-            s_shape = (nl, batch, 1, cfg.n_kv_heads, 1)
+            s_shape = (n_kv, batch, 1, cfg.n_kv_heads, 1)
             cache["kv_scale"] = (jnp.ones(s_shape, jnp.float32),
                                  jnp.ones(s_shape, jnp.float32))
-    if cfg.ssm is not None:
+    if n_ssm:
         nh = cfg.n_ssm_heads
         p = cfg.ssm.head_dim
-        cache["ssm"] = jnp.zeros((nl, batch, nh, p, cfg.ssm.state_dim),
+        cache["ssm"] = jnp.zeros((n_ssm, batch, nh, p, cfg.ssm.state_dim),
                                  jnp.float32)
         conv_dim = cfg.d_inner + 2 * cfg.ssm.state_dim
         cache["conv"] = jnp.zeros(
-            (nl, batch, cfg.ssm.conv_kernel - 1, conv_dim), dtype)
+            (n_ssm, batch, cfg.ssm.conv_kernel - 1, conv_dim), dtype)
     return cache
 
 
@@ -493,6 +633,9 @@ def _serve_scan(cfg: ArchConfig, params, x, positions, cache, pos):
     """The layers over ``x`` with the cache: the whole cache rides in the
     scan's carry, and each layer writes only its new entries into it, so a
     donated cache is updated in place and never copied whole."""
+    if cfg.layer_types:
+        x, _, cache = _typed_layers(cfg, params, x, positions, cache, pos)
+        return x, cache
     kinds = layer_kinds(cfg)
 
     def body(carry, scanned):
@@ -514,7 +657,7 @@ def prefill(cfg: ArchConfig, params, batch, cache):
     x, new_cache = _serve_scan(cfg, params, x, positions, cache,
                                pos=jnp.zeros((), jnp.int32))
     x = L.rms_norm(x[:, -1:], params["ln_f"], cfg.norm_eps)
-    return (x @ _head_matrix(cfg, params))[:, 0], new_cache
+    return _logits(cfg, x, _head_matrix(cfg, params))[:, 0], new_cache
 
 
 def decode_step(cfg: ArchConfig, params, cache, token, pos):
@@ -534,12 +677,13 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos):
                 params["ln_f"].dtype)
         else:
             x = jnp.take(emb, token, axis=0)
+        x = _mul(x, cfg.embedding_multiplier)
     b = x.shape[0]
     positions = jnp.broadcast_to(pos[None], (b, 1)) \
         if jnp.ndim(pos) == 0 else pos[:, None]
     x, new_cache = _serve_scan(cfg, params, x, positions, cache, pos=pos)
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return (x @ _head_matrix(cfg, params))[:, 0], new_cache
+    return _logits(cfg, x, _head_matrix(cfg, params))[:, 0], new_cache
 
 
 def _jit_named(fn, name: str, donate: int):

@@ -4,6 +4,8 @@ Follows the minimal SSD formulation of arXiv:2405.21060: within a chunk
 the output is computed in dual (attention-like) form with the decay mask
 L, across chunks a small recurrence over the (heads, head_dim, state)
 tensor carries the SSM state.  Single B/C group shared across heads.
+The output passes Mamba-2's gated RMSNorm, gate first: ``rmsnorm(y *
+silu(z)) * (1 + norm)`` over all ``d_inner`` channels (one group).
 """
 from __future__ import annotations
 
@@ -126,9 +128,19 @@ def ssd_scan(xh, dt, a_log, bmat, cmat, chunk: int):
     return y.astype(xh.dtype), hT
 
 
-def ssm_block(params, x, ssm: SsmConfig, state=None, conv_state=None):
+def gated_norm(y, z, gamma, eps):
+    """Mamba-2's gated RMSNorm: the gate ``silu(z)`` first, then the norm
+    (weight ``1 + gamma``), in float32."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    var = jnp.mean(jnp.square(g), -1, keepdims=True)
+    return g * jax.lax.rsqrt(var + eps) * (1 + gamma.astype(jnp.float32))
+
+
+def ssm_block(params, x, ssm: SsmConfig, state=None, conv_state=None,
+              eps: float = 1e-5):
     """Full Mamba2 mixer.  Train/prefill: state=None -> chunked scan.
     Decode (S==1): pass (state, conv_state), returns updated states.
+    ``eps`` is the gated norm's (the configuration's ``norm_eps``).
 
     Returns (y, new_state, new_conv_state).
     """
@@ -143,10 +155,11 @@ def ssm_block(params, x, ssm: SsmConfig, state=None, conv_state=None):
 
     if s == 1 and conv_state is not None:
         # decode: roll the conv window
-        window = jnp.concatenate([conv_state, xbc], axis=1)  # (B,k,conv)
-        conv_out = (window * params["conv_w"]).sum(axis=1, keepdims=True) \
-            + params["conv_b"]
-        new_conv_state = window[:, 1:]
+        with jax.named_scope("mamba.state"):
+            window = jnp.concatenate([conv_state, xbc], axis=1)
+            conv_out = (window * params["conv_w"]).sum(
+                axis=1, keepdims=True) + params["conv_b"]
+            new_conv_state = window[:, 1:]
     else:
         conv_out = _causal_conv(xbc, params["conv_w"], params["conv_b"])
         k = params["conv_w"].shape[0]
@@ -160,21 +173,19 @@ def ssm_block(params, x, ssm: SsmConfig, state=None, conv_state=None):
     xh = xin.reshape(b, s, nh, p)
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
 
-    if s == 1 and state is not None:
-        # recurrent decode step
-        dA = jnp.exp(dt[:, 0].astype(jnp.float32) * a)  # (B,nh)
-        dbx = jnp.einsum("bn,bhp,bh->bhpn", bmat[:, 0], xh[:, 0],
-                         dt[:, 0].astype(jnp.float32))
-        new_state = state * dA[..., None, None] + dbx
-        y = jnp.einsum("bn,bhpn->bhp", cmat[:, 0], new_state)
-        y = y[:, None]                                  # (B,1,nh,p)
-    else:
-        y, new_state = ssd_scan(xh, dt, params["a_log"], bmat, cmat,
-                                ssm.chunk)
+    with jax.named_scope("mamba.state"):
+        if s == 1 and state is not None:
+            # recurrent decode step
+            dA = jnp.exp(dt[:, 0].astype(jnp.float32) * a)  # (B,nh)
+            dbx = jnp.einsum("bn,bhp,bh->bhpn", bmat[:, 0], xh[:, 0],
+                             dt[:, 0].astype(jnp.float32))
+            new_state = state * dA[..., None, None] + dbx
+            y = jnp.einsum("bn,bhpn->bhp", cmat[:, 0], new_state)
+            y = y[:, None]                              # (B,1,nh,p)
+        else:
+            y, new_state = ssd_scan(xh, dt, params["a_log"], bmat, cmat,
+                                    ssm.chunk)
     y = y + xh * params["d_skip"][None, None, :, None]
     y = y.reshape(b, s, d_in)
-    # gated RMSNorm (mamba2)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), -1, keepdims=True)
-    y = y * jax.lax.rsqrt(var + 1e-6) * (1 + params["norm"])
-    y = (y * jax.nn.silu(z)).astype(x.dtype)
+    y = gated_norm(y, z, params["norm"], eps).astype(x.dtype)
     return y @ params["out_proj"], new_state, new_conv_state

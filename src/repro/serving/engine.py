@@ -14,7 +14,8 @@ on-device LLM decode).
 
 Program spans (``repro.obs``): ``serve.step`` per call, ``serve.queue``
 per request from submit to admission, ``serve.admit`` per admission
-(``serve.prefill``, ``serve.merge``, ``serve.first_token``), and per
+(``serve.prefill``, ``serve.merge`` with the ``kv_bytes`` and
+``state_bytes`` of the slot it merges, ``serve.first_token``), and per
 decode ``serve.h2d``, ``serve.decode``, ``serve.sample``,
 ``serve.readback`` and ``serve.plan``.
 
@@ -72,6 +73,13 @@ class ServingEngine:
         self.slots = slots
         self.max_seq = max_seq
         self.cache = M.init_cache(cfg, slots, max_seq, jnp.float32)
+        # bytes of one slot's K/V and of its recurrent (SSM, conv) state:
+        # what an admission merges into the batched cache
+        per_slot = {k: sum(x.nbytes for x in jax.tree.leaves(v)) // slots
+                    for k, v in self.cache.items()}
+        self._merge_bytes = dict(
+            kv_bytes=per_slot.get("kv", 0) + per_slot.get("kv_scale", 0),
+            state_bytes=per_slot.get("ssm", 0) + per_slot.get("conv", 0))
         self.active: list[Optional[Request]] = [None] * slots
         self.pos = np.zeros(slots, dtype=np.int32)
         self.waiting: list[Request] = []
@@ -146,7 +154,7 @@ class ServingEngine:
         # merge the single-row cache into the batched cache at `slot`
         def merge(full, one):
             return full.at[:, slot:slot + 1].set(one)
-        with obs.span("serve.merge"):
+        with obs.span("serve.merge", **self._merge_bytes):
             self.cache = jax.tree.map(merge, self.cache, tmp_cache)
         self.pos[slot] = s
         with obs.span("serve.first_token"):

@@ -36,17 +36,21 @@ class GemvSite:
 
 
 def decode_gemv_sites(cfg: ArchConfig) -> list[GemvSite]:
-    """Weight matrices a single-token decode multiplies against."""
+    """Weight matrices a single-token decode multiplies against, each
+    counted over the layers that hold it (``layer_types``: attention
+    sites in the attention layers, SSM sites in the Mamba layers)."""
     sites = []
     L = cfg.n_layers
     d = cfg.d_model
-    if not cfg.attention_free:
+    l_attn = cfg.n_layers_of("attention") if cfg.layer_types else L
+    l_ssm = cfg.n_layers_of("mamba") if cfg.layer_types else L
+    if not cfg.attention_free and l_attn:
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-        sites += [GemvSite("attn.wq", hq * hd, d, L),
-                  GemvSite("attn.wk", hkv * hd, d, L),
-                  GemvSite("attn.wv", hkv * hd, d, L),
-                  GemvSite("attn.wo", d, hq * hd, L)]
-    if cfg.family == "moe":
+        sites += [GemvSite("attn.wq", hq * hd, d, l_attn),
+                  GemvSite("attn.wk", hkv * hd, d, l_attn),
+                  GemvSite("attn.wv", hkv * hd, d, l_attn),
+                  GemvSite("attn.wo", d, hq * hd, l_attn)]
+    if cfg.moe is not None:
         e, k = cfg.moe.n_experts, cfg.moe.top_k
         n = 3 if cfg.mlp == "swiglu" else 2
         # per token only top-k experts run; router is a small GEMV too
@@ -54,6 +58,12 @@ def decode_gemv_sites(cfg: ArchConfig) -> list[GemvSite]:
         sites += [GemvSite(f"moe.w{i}", cfg.d_ff, d, L * k)
                   for i in range(n - 1)]
         sites.append(GemvSite("moe.wo", d, cfg.d_ff, L * k))
+        sff = cfg.moe.shared_d_ff
+        if sff:
+            # the shared expert's input projection is gate and up
+            sites += [GemvSite("moe.shared_wg", sff, d, L),
+                      GemvSite("moe.shared_wi", sff, d, L),
+                      GemvSite("moe.shared_wo", d, sff, L)]
     elif cfg.d_ff > 0:
         n = 3 if cfg.mlp == "swiglu" else 2
         sites += [GemvSite(f"mlp.w{i}", cfg.d_ff, d, L)
@@ -62,8 +72,8 @@ def decode_gemv_sites(cfg: ArchConfig) -> list[GemvSite]:
     if cfg.ssm is not None:
         di = cfg.d_inner
         proj = 2 * di + 2 * cfg.ssm.state_dim + cfg.n_ssm_heads
-        sites += [GemvSite("ssm.in_proj", proj, d, L),
-                  GemvSite("ssm.out_proj", d, di, L)]
+        sites += [GemvSite("ssm.in_proj", proj, d, l_ssm),
+                  GemvSite("ssm.out_proj", d, di, l_ssm)]
     sites.append(GemvSite("lm_head", cfg.vocab_padded, d, 1))
     return sites
 

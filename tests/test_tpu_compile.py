@@ -141,6 +141,51 @@ def test_gemma3_4b_prefill_fits_one_chip(one_chip, gemma_params):
     assert _hbm_bytes(compiled) < V5E_HBM_BYTES
 
 
+@pytest.fixture(scope="module")
+def granite4h(one_chip):
+    """granite-4.0-h-small as the benchmark serves it (10 layers, 9 of 72
+    experts, 16 slots x 13,312 positions), bf16 weights."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import registry
+    c = registry.config(registry.load_benchmark(), "granite-4.0-h-small")
+    cfg = registry.arch(c).arch_config(c)
+    return cfg, c["serve"], _on(one_chip, M.param_specs(cfg, jnp.bfloat16))
+
+
+def test_granite4h_decode_step_updates_both_caches_in_place(one_chip,
+                                                            granite4h):
+    """The K/V of the attention layer and the SSM and conv state of the
+    Mamba layers are aliased and never copied whole, and the step fits."""
+    cfg, sv, params = granite4h
+    slots = sv["slots"]
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, slots, sv["max_seq"], jnp.float32)))
+    token = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = M.jit_decode_step(cfg).lower(params, cache, token,
+                                            pos).compile()
+    ma = compiled.memory_analysis()
+    nbytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert ma.alias_size_in_bytes >= nbytes
+    assert ma.temp_size_in_bytes < cache["ssm"].size * 4
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_granite4h_longest_prefill_fits_one_chip(one_chip, granite4h):
+    cfg, sv, params = granite4h
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, 1, sv["max_seq"], jnp.float32)))
+    tokens = jax.ShapeDtypeStruct((1, 12288), jnp.int32, sharding=one_chip)
+    compiled = M.jit_prefill(cfg).lower(params, {"tokens": tokens},
+                                        cache).compile()
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
 def test_pim_gemv_fp_compiles(one_chip):
     w = jax.ShapeDtypeStruct((4096, 4096), jnp.float8_e4m3fn,
                              sharding=one_chip)
