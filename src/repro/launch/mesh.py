@@ -20,12 +20,20 @@ import jax
 MESH_OVERRIDE = None
 
 
+def _mesh(shape, axes):
+    """A mesh whose axes the partitioner places (``Auto``), so that a
+    sharding constraint such as ``moe.DISPATCH_SPEC`` may name them:
+    ``jax.make_mesh``'s default ``Explicit`` axes refuse one."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     if MESH_OVERRIDE is not None and not multi_pod:
-        return jax.make_mesh(MESH_OVERRIDE, ("data", "model"))
+        return _mesh(MESH_OVERRIDE, ("data", "model"))
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple[str, ...]:
@@ -36,7 +44,7 @@ def data_axes(mesh) -> tuple[str, ...]:
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the locally visible devices (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_lane_mesh(n: int | None = None):

@@ -1,14 +1,21 @@
 """Mixture-of-Experts block: top-k routing, dropless by default.
 
-GShard-style position-in-expert dispatch (one (N, E) cumsum per top-k
-slot) — O(N·E) intermediates, no (N, E, C) dispatch tensors and no global
-sort.  Capacity is derived from the flattened token count so it never
-binds (routing is batching-invariant — prefill, teacher-forced decode
-and B>1 decode steps agree exactly); pass ``drop_tokens=True`` to get
-the legacy capacity-factor-bounded buffer for memory-constrained
-training (the 1M-token train_4k cells).  Experts are sharded on the
-model axis; the scatters/gathers lower to the expected all-to-all-class
-collectives under SPMD.
+Two dispatches, chosen at trace time.  A few tokens (a decode step) go
+through a GShard-style buffer: position-in-expert by one (N, E) cumsum
+per top-k slot, every held expert over ``cap`` rows, no (N, E, C)
+dispatch tensors and no global sort.  On one device, many tokens (a
+prefill) are sorted by expert and run as grouped matmuls
+(``jax.lax.ragged_dot``) over the rows the router sent (``_grouped``),
+so the work follows the routed count rather than every held expert over
+every token.  Under a mesh of several devices (``_spmd``: the dry-run's
+production meshes) the buffer runs at every token count: experts are
+sharded on the model axis, the buffer's scatters/gathers lower to
+all-to-all-class collectives, and ``DISPATCH_SPEC`` places its rows.
+Capacity is derived from the flattened token count so it never binds
+(routing is batching-invariant — prefill, teacher-forced decode and B>1
+decode steps agree exactly); ``drop_tokens=True`` gives the legacy
+capacity-factor-bounded buffer, which drops what overflows it (no caller
+in the program passes it).
 
 Expert parallelism without the exchange: a layer whose ``wi`` holds
 fewer experts than the router's width is one chip's share.  It routes
@@ -107,8 +114,7 @@ def moe_apply(params, x, *, top_k: int, capacity_factor: float,
     position-in-expert (a cumsum across the flattened *batch* rows)
     overflowed the tiny per-step capacity (see
     ``test_moe_decode_drops_batch_rows``).  ``drop_tokens=True`` restores
-    the capacity-factor-bounded dispatch buffer for memory-constrained
-    training runs (the 1M-token train_4k cells), accepting the drops.
+    the capacity-factor-bounded dispatch buffer, accepting the drops.
     """
     b, s, d = x.shape
     e_all = params["router"].shape[-1]
@@ -127,11 +133,50 @@ def moe_apply(params, x, *, top_k: int, capacity_factor: float,
     return y.reshape(b, s, d), aux
 
 
+# What the grouped form costs per held expert beyond the routed rows, in
+# rows of the buffer, from the routed experts timed on a TPU v5e
+# (``benchmarks/moe_grouped_speed.py``, ``layer/*``): grouped and buffer
+# cross between 384 and 512 tokens in granite-moe-3b-a800m's whole layer
+# (1.54 ms each at 384; 1.56 against 2.20 at 512) and between 512 and
+# 640 in granite-4.0-h-small's share, which gathers and gates its bound
+# of 9 rows a token whatever the router sends (1.67 against 1.60 at 512,
+# 1.65 against 1.83 at 640).  384 puts every prompt and decode step of
+# the MoE cells on the faster side; the share at 446-639 tokens, in no
+# cell, may run grouped about 4 % slower.
+OVERHEAD_ROWS = 384
+
+
+def _grouped(n: int, top_k: int, e_all: int) -> bool:
+    """Whether the grouped dispatch runs ``n`` tokens, decided by shape.
+
+    The buffer holds ``e·n`` rows; the router sends the ``e`` held experts
+    ``n·k·e/e_all`` of them on average, and the grouped form costs
+    ``OVERHEAD_ROWS`` more per expert: it wins where ``e·n`` exceeds that
+    sum, which does not depend on ``e``.  A prefill of 481 tokens or more
+    takes it in granite-moe-3b-a800m (40 experts, top-8), of 446 or more
+    in granite-4.0-h-small (72, top-10); a decode step's few rows keep the
+    buffer.
+    """
+    return n * (e_all - top_k) > OVERHEAD_ROWS * e_all
+
+
+def _spmd() -> bool:
+    """Whether the trace runs under a mesh of several devices (the
+    dry-run's ``with mesh:``).  There the buffer runs at every token
+    count: the partitioner lowers its dispatch to all-to-all, placed by
+    ``DISPATCH_SPEC``, and keeps experts sharded on the model axis where
+    they are, while it partitions a ragged dot over experts by gathering
+    every expert's weights onto each device (v5e 2x2 compile)."""
+    from jax._src.mesh import thread_resources   # ``with mesh:`` context
+    return max(thread_resources.env.physical_mesh.size,
+               jax.sharding.get_abstract_mesh().size) > 1
+
+
 def _routed(params, xt, top_k, capacity_factor, mlp_kind, drop_tokens,
             first):
     """The held experts' weighted outputs (N, d), the router's softmax
     and its top-k ids."""
-    n, d = xt.shape
+    n = xt.shape[0]
     e_all = params["router"].shape[-1]
     e = params["wi"].shape[0]
     logits = (xt @ params["router"]).astype(jnp.float32)
@@ -142,9 +187,38 @@ def _routed(params, xt, top_k, capacity_factor, mlp_kind, drop_tokens,
     # ids among the held experts; an assignment elsewhere is not held
     local = gate_idx if e == e_all else gate_idx - first
 
-    cap = int(max(1, math.ceil(n * top_k * capacity_factor / e))) \
-        if drop_tokens else n
+    if not drop_tokens and not _spmd() and _grouped(n, top_k, e_all):
+        with jax.named_scope("moe.grouped"):
+            got = _grouped_rows(params, xt, local, mlp_kind)
+    else:
+        cap = int(max(1, math.ceil(n * top_k * capacity_factor / e))) \
+            if drop_tokens else n
+        got = _buffered_rows(params, xt, local, mlp_kind, cap)
+    y = jnp.zeros_like(xt)
+    for slot, (rows, ok) in enumerate(got):
+        w = (gate_vals[:, slot] * ok).astype(y.dtype)
+        y = y + rows * w[:, None]
 
+    from jax.ad_checkpoint import checkpoint_name
+    return checkpoint_name(y, "moe_out"), probs, gate_idx
+
+
+def _experts(h, params, mlp_kind, mm):
+    """The expert MLP over rows ``h``; ``mm(h, w)`` applies each row's
+    expert of the stacked weight ``w``."""
+    if mlp_kind == "swiglu":
+        hid = jax.nn.silu(mm(h, params["wg"])) * mm(h, params["wi"])
+    else:
+        hid = jax.nn.gelu(mm(h, params["wi"]))
+    return mm(hid, params["wo"])
+
+
+def _buffered_rows(params, xt, local, mlp_kind, cap):
+    """Per top-k slot, each token's row of its expert's output and whether
+    it was computed: every held expert over ``cap`` rows of a buffer."""
+    n, d = xt.shape
+    e_all = params["router"].shape[-1]
+    e = params["wi"].shape[0]
     # GShard dispatch: per top-k slot, position-in-expert via cumsum.
     # An expert share fills its buffer with one gather of token rows, the
     # row of each (expert, position) found by a scatter of token ids: a
@@ -156,7 +230,7 @@ def _routed(params, xt, top_k, capacity_factor, mlp_kind, drop_tokens,
         src = jnp.full((e * cap,), n, jnp.int32)       # n: a zero row
     locs = []
     counts = jnp.zeros((e,), jnp.int32)
-    for slot in range(top_k):
+    for slot in range(local.shape[1]):
         # one_hot of an id outside [0, e) is all zeros: not dispatched
         onehot = jax.nn.one_hot(local[:, slot], e, dtype=jnp.int32)
         pos = jnp.cumsum(onehot, axis=0) - onehot + counts[None, :]
@@ -178,21 +252,40 @@ def _routed(params, xt, top_k, capacity_factor, mlp_kind, drop_tokens,
             jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)]), src,
             axis=0), DISPATCH_SPEC)
 
-    he = buf.reshape(e, cap, d)
-    if mlp_kind == "swiglu":
-        hid = jax.nn.silu(jnp.einsum("ecd,edf->ecf", he, params["wg"])) \
-            * jnp.einsum("ecd,edf->ecf", he, params["wi"])
-    else:
-        hid = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", he, params["wi"]))
-    out_e = jnp.einsum("ecf,efd->ecd", hid, params["wo"])
+    out_e = _experts(buf.reshape(e, cap, d), params, mlp_kind,
+                     lambda h, w: jnp.einsum("ecd,edf->ecf", h, w))
     out_flat = out_e.reshape(e * cap, d)
+    # lazily, so each slot's gather is traced beside its weighting
+    return ((jnp.take(out_flat, jnp.minimum(idx, e * cap - 1), axis=0), ok)
+            for idx, ok in locs)
 
-    y = jnp.zeros_like(xt)
-    for slot, (idx, ok) in enumerate(locs):
-        gathered = jnp.take(out_flat, jnp.minimum(idx, e * cap - 1),
-                            axis=0)
-        w = (gate_vals[:, slot] * ok).astype(y.dtype)
-        y = y + gathered * w[:, None]
 
-    from jax.ad_checkpoint import checkpoint_name
-    return checkpoint_name(y, "moe_out"), probs, gate_idx
+def _grouped_rows(params, xt, local, mlp_kind):
+    """Per top-k slot, each token's row of its expert's output and whether
+    it is held: the assignments sorted by expert, one gather of their
+    token rows, grouped matmuls over the routed rows alone, and a gather
+    back per slot through the inverse of the sort."""
+    n = xt.shape[0]
+    k = local.shape[1]
+    e = params["wi"].shape[0]
+    flat = local.reshape(-1)                               # token-major
+    held = (flat >= 0) & (flat < e)
+    ids = jnp.where(held, flat, e)                  # e: not held, last
+    order = jnp.argsort(ids, stable=True)
+    # a token's k experts are distinct, so at most min(k, e) are held
+    m = n * min(k, e)
+    sizes = jax.nn.one_hot(ids, e, dtype=jnp.int32).sum(axis=0)
+    # in range by construction: "clip" spares the out-of-range fill's pass
+    h = jnp.take(xt, order[:m] // k, axis=0, mode="clip")
+    out = _experts(h, params, mlp_kind,
+                   lambda h, w: jax.lax.ragged_dot(h, w, sizes))
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype), unique_indices=True)
+    back = back.reshape(n, k)
+    held = held.reshape(n, k)
+    # a gather per slot, as the buffer's: one of (n·k, d) rows would
+    # raise the peak; rows not held are zero, whatever ``out`` holds there
+    return [(jnp.where(held[:, slot, None],
+                       jnp.take(out, back[:, slot], axis=0, mode="clip"),
+                       0),
+             held[:, slot]) for slot in range(k)]

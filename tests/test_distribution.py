@@ -93,6 +93,28 @@ def test_input_shardings_match_specs():
             assert s1 == s2, (arch, sname, s1, s2)
 
 
+def test_dispatch_spec_reaches_the_production_mesh(monkeypatch):
+    """``moe.DISPATCH_SPEC`` (the dry-run's ``moe-shard`` variants) changes
+    the program lowered under the production mesh, whose axes are
+    ``Auto``: on ``jax.make_mesh``'s default ``Explicit`` axes the
+    constraint is refused, and the knob did nothing."""
+    from repro.launch import mesh as MESH
+    from repro.models import moe as MOE
+    monkeypatch.setattr(MESH, "MESH_OVERRIDE", (1, 1))
+    mesh = MESH.make_production_mesh()
+    p = MOE.moe_init(jax.random.PRNGKey(0), 16, 8, 4, "swiglu")
+    x = jnp.ones((2, 4, 16))
+
+    def lowered(spec):
+        monkeypatch.setattr(MOE, "DISPATCH_SPEC", spec)
+        with mesh:
+            return jax.jit(lambda p, x: MOE.moe_apply(
+                p, x, top_k=2, capacity_factor=1.0,
+                mlp_kind="swiglu")[0]).lower(p, x).as_text()
+
+    assert lowered(("data", None)) != lowered(None)
+
+
 # --- HLO parser ---------------------------------------------------------
 
 HLO_SAMPLE = """

@@ -283,19 +283,26 @@ def test_shared_expert_and_share_in_a_uniform_moe():
 def test_device_ops_carry_the_mechanisms_names(hybrid, which):
     """The compiled step's operations are named by ``jax.named_scope``:
     the Mamba-2 mixer, its state update (the recurrence in decode, the
-    chunked scan in prefill), attention, the routed and shared experts."""
+    chunked scan in prefill), attention, the routed and shared experts,
+    and in prefill, whose prompt is long enough, the grouped dispatch."""
     _, _, cfg, params = hybrid
+    scopes = ("mamba.mix/", "mamba.mix/mamba.state/", "/attn/",
+              "moe.routed/", "moe.shared/")
     if which == "decode_step":
         text = M.jit_decode_step(cfg).lower(
             params, M.init_cache(cfg, 2, 32, jnp.float32),
             jnp.zeros((2, 1), jnp.int32),
             jnp.zeros((2,), jnp.int32)).compile().as_text()
+        assert "moe.grouped" not in text
     else:
+        e_all, k = cfg.moe.n_experts, cfg.moe.top_k
+        n = MOE.OVERHEAD_ROWS * e_all // (e_all - k) + 1
+        assert MOE._grouped(n, k, e_all)
         text = M.jit_prefill(cfg).lower(
-            params, {"tokens": jnp.zeros((1, 20), jnp.int32)},
-            M.init_cache(cfg, 1, 32, jnp.float32)).compile().as_text()
-    for scope in ("mamba.mix/", "mamba.mix/mamba.state/", "/attn/",
-                  "moe.routed/", "moe.shared/"):
+            params, {"tokens": jnp.zeros((1, n), jnp.int32)},
+            M.init_cache(cfg, 1, n, jnp.float32)).compile().as_text()
+        scopes += ("moe.routed/moe.grouped/",)
+    for scope in scopes:
         assert scope in text, scope
 
 

@@ -12,6 +12,7 @@ worker imports every test file.  Pallas lane-kernel and integer-GEMV
 refusals are pinned as strict xfails, so the PR that fixes either flips
 them.
 """
+import numpy as np
 import pytest
 
 import jax
@@ -29,7 +30,8 @@ V5E_HBM_BYTES = int(15.75 * 2**30)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The described topology's four chips."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     with pytest.MonkeyPatch.context() as mp:
@@ -44,9 +46,14 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _on(sharding, tree):
@@ -215,3 +222,44 @@ def test_pim_gemv_int_compiles(one_chip, w_bits):
     xs = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
     pim_gemv.pim_gemv_int.lower(wq, xq, ws, xs, w_bits=w_bits,
                                 interpret=False).compile()
+
+
+def test_moe_training_under_a_mesh_keeps_experts_sharded(v5e_2x2,
+                                                         monkeypatch):
+    """Under a 2x2 mesh a training step's MoE block keeps the buffer at a
+    token count that takes the grouped dispatch on one chip: the
+    partitioner lowers its dispatch to all-to-all, gathers no expert's
+    weights (it does gather them all for a ragged dot over experts), and
+    ``DISPATCH_SPEC`` changes the program."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.models import moe as MOE
+    mesh = Mesh(np.array(v5e_2x2).reshape(2, 2), ("data", "model"))
+    d, ff, e, k, n = 256, 128, 8, 2, 2048
+    assert MOE._grouped(n, k, e)
+    params = jax.eval_shape(lambda: MOE.moe_init(
+        jax.random.PRNGKey(0), d, ff, e, "swiglu", jnp.bfloat16))
+    params = {name: jax.ShapeDtypeStruct(w.shape, w.dtype, sharding=(
+        NamedSharding(mesh, P("model", None, None)) if w.ndim == 3 else
+        NamedSharding(mesh, P()))) for name, w in params.items()}
+    x = jax.ShapeDtypeStruct((4, n // 4, d), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def loss(p, x):
+        y, aux = MOE.moe_apply(p, x, top_k=k, capacity_factor=1.0,
+                               mlp_kind="swiglu")
+        return (y.astype(jnp.float32) ** 2).sum() + aux
+
+    texts = []
+    for spec in (None, ("data", None)):
+        monkeypatch.setattr(MOE, "DISPATCH_SPEC", spec)
+        with mesh:
+            texts.append(jax.jit(jax.grad(loss)).lower(
+                params, x).compile().as_text())
+    for text in texts:
+        assert "moe.grouped" not in text and "all-to-all" in text
+        gathered = re.findall(r"= bf16\[(\d+),(\d+),(\d+)\]\S* all-gather",
+                              text)
+        assert not [s for s in gathered
+                    if sorted(map(int, s)) == sorted((e, d, ff))]
+    assert texts[0] != texts[1]
